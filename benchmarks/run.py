@@ -57,6 +57,8 @@ def main() -> None:
                          "best-of-3)")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import bench_kernels, bench_ops, common
     from benchmarks.common import FAST
 

@@ -10,10 +10,12 @@ XID-branching policy (§4.3.5).
 """
 import tempfile
 
+from repro.compile_cache import use_compile_cache
 from repro.launch.train import run_training
 
 
 def main():
+    use_compile_cache()
     for policy in ("fixed", "xid_branch"):
         print(f"\n=== policy: {policy} ===")
         # fresh checkpoint dir per run: restoring a stale step-60

@@ -11,12 +11,14 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.cluster import CampaignConfig, ClusterSim
 from repro.core.precursor import DetectorConfig, PrecursorDetector, evaluate
 from repro.core.retry import chain_stats
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--days", type=float, default=15.0,
                     help="campaign length (telemetry on; 73 for paper scale)")

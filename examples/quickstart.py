@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.launch.steps import (make_serve_step, make_train_step,
                                 synthetic_batch, synthetic_decode_inputs)
@@ -19,6 +20,7 @@ from repro.optim import AdamW
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
     ap.add_argument("--steps", type=int, default=10)

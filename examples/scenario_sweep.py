@@ -29,10 +29,12 @@ the numpy engines:
 import argparse
 import warnings
 
+from repro.compile_cache import use_compile_cache
 from repro.ops import SweepRunner, get_scenario, list_scenarios
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scenarios",
                     default="paper-faithful,no-auto-retry,smart-retry",
@@ -134,18 +136,15 @@ def main():
     if args.mc_seeds and wavefront != "numpy":
         # compiled lanes pad to the next power of two (>= 64): a
         # non-bucketed seed count pays for lanes it never reads
-        try:
-            from repro.kernels.common import next_pow2
-            bucket = max(next_pow2(args.mc_seeds), 64)
-            if bucket != args.mc_seeds:
-                warnings.warn(
-                    f"--mc-seeds {args.mc_seeds} is not a power-of-two "
-                    "lane bucket: the compiled pass pads its lane axis "
-                    f"to the next bucket, so up to {bucket} seeds cost "
-                    "the same device wall clock (and every distinct "
-                    "count compiles its own program)", stacklevel=1)
-        except ImportError:
-            pass
+        from repro.kernels.common import next_pow2
+        bucket = max(next_pow2(args.mc_seeds), 64)
+        if bucket != args.mc_seeds:
+            warnings.warn(
+                f"--mc-seeds {args.mc_seeds} is not a power-of-two "
+                "lane bucket: the compiled pass pads its lane axis "
+                f"to the next bucket, so up to {bucket} seeds cost "
+                "the same device wall clock (and every distinct "
+                "count compiles its own program)", stacklevel=1)
 
     names = list_scenarios() if args.scenarios == "all" \
         else [s.strip() for s in args.scenarios.split(",") if s.strip()]

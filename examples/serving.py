@@ -4,10 +4,12 @@
 """
 import argparse
 
+from repro.compile_cache import use_compile_cache
 from repro.launch.serve import run_serving
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
     ap.add_argument("--batch", type=int, default=4)

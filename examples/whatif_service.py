@@ -17,6 +17,7 @@ engine pass, and repeats hit the canonical-key LRU.
 import argparse
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.ops import get_scenario
 from repro.serve import (ServiceConfig, SurfaceSpec, SweepSurface,
                          WhatIfService)
@@ -32,6 +33,7 @@ def show(label: str, answer) -> None:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--days", type=float, default=3.0,
                     help="campaign length for the demo queries (shorter "

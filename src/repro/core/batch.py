@@ -338,18 +338,13 @@ class BatchedCampaignEngine:
         # surface: route eligible batches through the device core (the
         # object-materializing `run` path stays numpy by construction)
         if self.wavefront_backend != "numpy":
-            try:
-                from repro.kernels.wavefront import (
-                    resolve_wavefront_backend, run_findings_compiled)
-            except ImportError:          # no jax: auto degrades to numpy
-                if self.wavefront_backend != "auto":
-                    raise
-            else:
-                backend = resolve_wavefront_backend(
-                    self.wavefront_backend, self.cfg, len(seeds))
-                if backend != "numpy":
-                    return run_findings_compiled(self.cfg, seeds,
-                                                 backend=backend)
+            from repro.kernels.wavefront import (resolve_wavefront_backend,
+                                                 run_findings_compiled)
+            backend = resolve_wavefront_backend(
+                self.wavefront_backend, self.cfg, len(seeds))
+            if backend != "numpy":
+                return run_findings_compiled(self.cfg, seeds,
+                                             backend=backend)
         B = self._simulate(seeds, materialize=False)
         return [self._findings(B, i) for i in range(B.S)]
 
@@ -1226,28 +1221,22 @@ def run_findings_stacked(configs: Sequence[CampaignConfig],
     seeds = list(seeds)
     covered: Dict[int, List[dict]] = {}
     if wavefront_backend != "numpy":
-        try:
-            from repro.kernels.common import WAVEFRONT_MIN_SEEDS
-            from repro.kernels.wavefront import compiled_eligible
-            from repro.kernels.wavefront.ops import run_findings_grid
-        except ImportError:              # no jax: auto degrades to numpy
-            if wavefront_backend != "auto":
-                raise
-        else:
-            groups: Dict[int, List[int]] = {}
-            for i, cfg in enumerate(configs):
-                if compiled_eligible(ClusterSim(cfg).cfg):
-                    groups.setdefault(cfg.n_nodes, []).append(i)
-            dev = "xla" if wavefront_backend == "auto" \
-                else wavefront_backend
-            for idxs in groups.values():
-                if wavefront_backend == "auto" \
-                        and len(idxs) * len(seeds) < WAVEFRONT_MIN_SEEDS:
-                    continue             # too few lanes to beat numpy
-                per_cfg = run_findings_grid([configs[i] for i in idxs],
-                                            seeds, backend=dev)
-                for j, i in enumerate(idxs):
-                    covered[i] = per_cfg[j]
+        from repro.kernels.common import WAVEFRONT_MIN_SEEDS
+        from repro.kernels.wavefront import compiled_eligible
+        from repro.kernels.wavefront.ops import run_findings_grid
+        groups: Dict[int, List[int]] = {}
+        for i, cfg in enumerate(configs):
+            if compiled_eligible(ClusterSim(cfg).cfg):
+                groups.setdefault(cfg.n_nodes, []).append(i)
+        dev = "xla" if wavefront_backend == "auto" else wavefront_backend
+        for idxs in groups.values():
+            if wavefront_backend == "auto" \
+                    and len(idxs) * len(seeds) < WAVEFRONT_MIN_SEEDS:
+                continue                 # too few lanes to beat numpy
+            per_cfg = run_findings_grid([configs[i] for i in idxs],
+                                        seeds, backend=dev)
+            for j, i in enumerate(idxs):
+                covered[i] = per_cfg[j]
     out: List[Dict[int, List[dict]]] = []
     for i, cfg in enumerate(configs):
         findings = covered.get(i)

@@ -33,6 +33,13 @@ GANG_ROWS = 8        # lanes per gang-select block
 N_LANES = 128        # node-axis pad (TPU lane width)
 
 
+def _row_block(i):
+    """Block index of row-block ``i``.  The column index is an explicit
+    int32: the wavefront traces this kernel inside its x64 pass, where a
+    bare ``0`` would lower as i64 and Mosaic refuses mixed index types."""
+    return i, jnp.int32(0)
+
+
 # -- gang selection ----------------------------------------------------------
 
 def _gang_kernel(free_ref, job_ref, out_ref):
@@ -52,9 +59,9 @@ def _gang_blocks(free_f32, job_f32, *, interpret):
     return pl.pallas_call(
         _gang_kernel,
         grid=(L // GANG_ROWS,),
-        in_specs=[pl.BlockSpec((GANG_ROWS, npad), lambda i: (i, 0)),
-                  pl.BlockSpec((GANG_ROWS, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((GANG_ROWS, npad), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((GANG_ROWS, npad), _row_block),
+                  pl.BlockSpec((GANG_ROWS, 1), _row_block)],
+        out_specs=pl.BlockSpec((GANG_ROWS, npad), _row_block),
         out_shape=jax.ShapeDtypeStruct((L, npad), jnp.float32),
         interpret=interpret,
     )(free_f32, job_f32)
@@ -101,7 +108,7 @@ def _fabric_kernel(tb, size, infl, sbw, tq, ctx, slots, lbw, deg, nw,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _fabric_blocks(args2d, *, interpret):
     R, C = args2d[0].shape
-    spec = pl.BlockSpec((GANG_ROWS, C), lambda i: (i, 0))
+    spec = pl.BlockSpec((GANG_ROWS, C), _row_block)
     return pl.pallas_call(
         _fabric_kernel,
         grid=(R // GANG_ROWS,),

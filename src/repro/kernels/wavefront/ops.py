@@ -95,17 +95,28 @@ def resolve_wavefront_backend(backend: str, cfg: CampaignConfig,
 
 # -- device pass + cap-doubling driver ---------------------------------------
 
+def device_tables(tables: LaneTables) -> Dict[str, np.ndarray]:
+    """The lane tables as the device pass takes them: doubles cross to
+    the device (and ``rec_t`` back) as their int64 bit patterns (see
+    ``ref.py``): a TPU changes the low bits of a double it holds as
+    f64."""
+    return {k: v.view(np.int64) if v.dtype == np.float64 else v
+            for k, v in tables.device.items()}
+
+
 def _run_core(tables: LaneTables, backend: str, interpret: bool):
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    with enable_x64():
-        P = {k: jnp.asarray(v) for k, v in tables.device.items()}
+    with jax.enable_x64(True):
+        P = {k: jnp.asarray(v) for k, v in device_tables(tables).items()}
         out = wavefront_core(
             P, n_nodes=tables.n_nodes,
             n_sessions=tables.caps.n_sessions,
             n_iters=tables.caps.n_iters,
             backend=backend, interpret=interpret)
-        return {k: np.asarray(v) for k, v in out.items()}
+        host = {k: np.asarray(v) for k, v in out.items()}
+    host["rec_t"] = host["rec_t"].view(np.float64)
+    return host
 
 
 def _run_with_caps(build, backend: str, interpret: bool):
@@ -418,9 +429,9 @@ def fabric_query_batch(fabric, op, fanins, bytes_per_client, *,
         out = fabric_query_pallas(*(jnp.asarray(a) for a in args),
                                   interpret=interpret)
         return np.asarray(out, dtype=float)
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.kernels.wavefront.kernel import _fabric_ref_jit
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _fabric_ref_jit(*(jnp.asarray(a) for a in args))
         return np.asarray(out, dtype=float)

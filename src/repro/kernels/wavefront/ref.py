@@ -12,17 +12,22 @@ device processes **at most one kill event per lane per iteration** and
 holds the lane's clock (a "pending" iteration) until the queue at that
 instant drains — same event order, one extra iteration per queued event.
 
-Bitwise discipline (the parity contract with ``ClusterSim``): the loop
-body contains *no* fmul-feeding-fadd chain on parity-critical floats —
-XLA CPU would contract it into an FMA and drift 1 ulp from numpy.  All
-multiply-adds live in the host tapes/tables; the device only gathers,
-compares, selects, and performs lone adds (``pend = t + delay``).  Float
-accounting folds (checkpoint catch-up, lost work, run-hours, downtime)
-do not happen here at all: the device emits a per-iteration record
-stream — ``(rec_t, rec_flags)`` with the event bits below — plus integer
-accumulators and per-session gang bitmasks, and the host *replay*
-(``ops.py``) reruns the folds in numpy, where double arithmetic matches
-the scalar engine exactly.
+Bitwise discipline (the parity contract with ``ClusterSim``): every
+float the loop touches travels as the int64 bit pattern of its double.
+On a TPU v5e a host double held as a device f64 comes back with its
+low mantissa bits changed (up to 8 ulp; only doubles that are the sum
+of two f32 survive), and f64 division is coarser still, so no double is
+stored or computed as f64 here.  On non-negative doubles (every clock, delay and
+probability here) the bit patterns order exactly as the values do, so
+compares, min/max, gathers and selects are integer ops, and the one
+arithmetic op the loop needs, a lone add (``pend = t + delay``), is an
+exact round-half-even integer routine (`f64_add`).  All multiply-adds
+live in the host tapes/tables.  Float accounting folds (checkpoint
+catch-up, lost work, run-hours, downtime) do not happen here at all: the
+device emits a per-iteration record stream — ``(rec_t, rec_flags)`` with
+the event bits below — plus integer accumulators and per-session gang
+bitmasks, and the host *replay* (``ops.py``) reruns the folds in numpy,
+where double arithmetic matches the scalar engine exactly.
 
 The checkpoint catch-up in particular cannot be split across device
 iterations (``c + k1*i`` then ``+ k2*i`` differs bitwise from
@@ -35,11 +40,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-__all__ = ["wavefront_core", "F_VALID", "F_ADVANCE", "F_RUNNING",
-           "F_START", "F_ALLOCFAIL", "F_PREP_OK", "F_SESS_FAIL",
-           "F_LOST", "F_CHAIN_CLOSE", "F_FINALIZE"]
+__all__ = ["wavefront_core", "f64_add", "f64_night", "F_VALID",
+           "F_ADVANCE", "F_RUNNING", "F_START", "F_ALLOCFAIL", "F_PREP_OK",
+           "F_SESS_FAIL", "F_LOST", "F_CHAIN_CLOSE", "F_FINALIZE"]
 
 # rec_flags bits (replayed host-side in this order within an iteration)
 F_VALID = 1          # lane alive this iteration
@@ -53,8 +59,68 @@ F_LOST = 128         # lost-work event (RUNNING session was killed)
 F_CHAIN_CLOSE = 256  # retry chain closed (manual-intervention branch)
 F_FINALIZE = 512     # campaign end reached
 
-_EPS = 1e-12
 _ORD_MAX = jnp.iinfo(jnp.int32).max
+
+
+# -- doubles as int64 bit patterns ------------------------------------------
+
+def _bits(x: float) -> int:
+    return int(np.float64(x).view(np.int64))
+
+
+_EPS = _bits(1e-12)
+_INF = _bits(np.inf)
+_NAN = _bits(np.nan)
+_ONE = _bits(1.0)
+_NEG_ONE = _bits(-1.0)    # struct_until's "never": below every clock
+_MANT = (1 << 52) - 1
+_IMPL = 1 << 52
+_EXP_ALL = 0x7FF << 52
+
+
+def _finite(x):
+    return (x & _EXP_ALL) != _EXP_ALL
+
+
+def f64_add(a, b):
+    """IEEE-754 ``a + b`` rounded half to even, on int64 bit patterns of
+    non-negative doubles (+inf absorbs; NaN operands are never read)."""
+    hi, lo = jnp.maximum(a, b), jnp.minimum(a, b)
+    e_hi, e_lo = hi >> 52, lo >> 52
+    # mantissas with the implicit bit and 3 guard/round/sticky bits;
+    # subnormals share exponent 1 without the implicit bit
+    m_hi = jnp.where(e_hi > 0, (hi & _MANT) | _IMPL, hi & _MANT) << 3
+    m_lo = jnp.where(e_lo > 0, (lo & _MANT) | _IMPL, lo & _MANT) << 3
+    e_hi = jnp.maximum(e_hi, 1)
+    d = jnp.minimum(e_hi - jnp.maximum(e_lo, 1), 63)
+    aligned = lax.shift_right_logical(m_lo, d)
+    sticky = (aligned << d) != m_lo
+    s = m_hi + (aligned | sticky.astype(aligned.dtype))
+    carry = s >> 56
+    s = jnp.where(carry > 0, (s >> 1) | (s & 1), s)
+    e = e_hi + carry
+    grs = s & 7
+    s = s >> 3
+    s = s + ((grs > 4) | ((grs == 4) & ((s & 1) == 1)))
+    ovf = s >> 53
+    s = jnp.where(ovf > 0, s >> 1, s)
+    e = jnp.where(s < _IMPL, 0, e + ovf)
+    out = jnp.where(e >= 0x7FF, _INF, (e << 52) | (s & _MANT))
+    return jnp.where((hi >= _EXP_ALL) | (lo == 0), hi, out)
+
+
+def f64_night(t):
+    """The operator's off-hours test of ``_manual_delay`` — ``day >= 5 or
+    hour < 8 or hour > 20`` with ``hour = t % 24`` and ``day = (t // 24)
+    % 7`` — exact on the bit pattern of a non-negative clock ``t``."""
+    shift = jnp.clip(1075 - (t >> 52), 0, 63)
+    m = (t & _MANT) | _IMPL
+    whole = t >= _ONE
+    ip = jnp.where(whole, lax.shift_right_logical(m, shift), 0)
+    frac = jnp.where(whole, (ip << shift) != m, t != 0)
+    ip = jnp.minimum(ip, _ORD_MAX).astype(jnp.int32)
+    hour, day = ip % 24, (ip // 24) % 7
+    return (day >= 5) | (hour < 8) | (hour > 20) | ((hour == 20) & frac)
 
 
 def _row(tab, ptr):
@@ -113,21 +179,18 @@ def _sched_next(st, flags, P, mask, t, evt_delay_h, evt_has_xid,
         noticed = noticed | (mask & P["struct_stop"])
     dna_d = _row(P["dna"], n_att)
     delay = jnp.where(P["policy_xid"] & evt_has_xid, evt_delay_h, dna_d)
-    retry = mask & P["retry_on"] & jnp.isfinite(delay) \
+    retry = mask & P["retry_on"] & _finite(delay) \
         & (n_att < P["max_r"]) & ~noticed
-    st["pend"] = jnp.where(retry, t + delay, st["pend"])
+    st["pend"] = jnp.where(retry, f64_add(t, delay), st["pend"])
 
     man = mask & ~retry
     # manual-intervention branch: chain closes, operator responds with a
     # day/night exponential delay, and a misfixed root cause may extend
     # the structural-failure horizon
-    hour = lax.rem(t, 24.0)
-    day = lax.rem((t - hour) / 24.0, 7.0)
-    night = (day >= 5.0) | (hour < 8.0) | (hour > 20.0)
-    md = jnp.where(night, _row(P["man_night"], st["m_ptr"]),
+    md = jnp.where(f64_night(t), _row(P["man_night"], st["m_ptr"]),
                    _row(P["man_day"], st["m_ptr"]))
     st["m_ptr"] = st["m_ptr"] + man
-    pend_man = t + md
+    pend_man = f64_add(t, md)
     st["pend"] = jnp.where(man, pend_man, st["pend"])
     u_mis = _row(P["u"], st["u_ptr"])
     mis = man & (u_mis < P["p_misfix"])
@@ -136,7 +199,7 @@ def _sched_next(st, flags, P, mask, t, evt_delay_h, evt_has_xid,
     st["x_ptr"] = st["x_ptr"] + mis
     su = st["struct_until"]
     st["struct_until"] = jnp.where(
-        mis, jnp.maximum(su, pend_man + xh),
+        mis, jnp.maximum(su, f64_add(pend_man, xh)),
         jnp.where(man, jnp.minimum(su, pend_man), su))
     st["n_att"] = jnp.where(man, 0, st["n_att"])
     flags = flags | jnp.where(man, F_CHAIN_CLOSE, 0)
@@ -150,8 +213,9 @@ def _iteration(st, P, backend: str, interpret: bool):
     iota_n = lax.broadcasted_iota(jnp.int32, (L, n), 1)
     rows = jnp.arange(L)
     zero_b = jnp.zeros(L, dtype=bool)
-    nan_v = jnp.full(L, jnp.nan)
+    nan_v = jnp.full(L, _NAN, dtype=t.dtype)
     flags = jnp.zeros(L, dtype=jnp.int32)
+    t_eps = f64_add(t, _EPS)            # "due now" tolerance
 
     # 1. repairs due (node returns, isolation entry cleared)
     rep_act = (st["repair"] <= t[:, None]) & alive[:, None]
@@ -159,7 +223,7 @@ def _iteration(st, P, backend: str, interpret: bool):
     st["excl"] = st["excl"] & ~rep_act
     st["iso_reason"] = jnp.where(rep_act, 0, st["iso_reason"])
     st["iso_order"] = jnp.where(rep_act, _ORD_MAX, st["iso_order"])
-    st["repair"] = jnp.where(rep_act, jnp.inf, st["repair"])
+    st["repair"] = jnp.where(rep_act, _INF, st["repair"])
 
     # 3. pending attempt starts
     free = st["healthy"] & ~st["excl"]
@@ -183,7 +247,7 @@ def _iteration(st, P, backend: str, interpret: bool):
     rm = readmit[:, None] & (iota_n == rm_node[:, None])
     st["excl"] = st["excl"] & ~rm
     st["healthy"] = st["healthy"] | rm
-    st["repair"] = jnp.where(rm, jnp.inf, st["repair"])
+    st["repair"] = jnp.where(rm, _INF, st["repair"])
     st["iso_reason"] = jnp.where(rm, 0, st["iso_reason"])
     st["iso_order"] = jnp.where(rm, _ORD_MAX, st["iso_order"])
 
@@ -213,11 +277,11 @@ def _iteration(st, P, backend: str, interpret: bool):
                               _row(P["dur_cold"], st["u_ptr"]),
                               _row(P["dur_warm"], st["u_ptr"])))
     st["u_ptr"] = st["u_ptr"] + okm
-    st["prep_until"] = jnp.where(okm, t + dur, st["prep_until"])
+    st["prep_until"] = jnp.where(okm, f64_add(t, dur), st["prep_until"])
     st["prep_fails"] = jnp.where(okm, pf, st["prep_fails"])
     st["cur_on"] = st["cur_on"] | okm
     st["cur_run"] = st["cur_run"] & ~okm
-    st["pend"] = jnp.where(okm, jnp.inf, st["pend"])
+    st["pend"] = jnp.where(okm, _INF, st["pend"])
 
     # 4. PREPARING completions (incl. sessions opened this iteration
     # whose load duration underruns — the numpy step order does the same)
@@ -232,7 +296,7 @@ def _iteration(st, P, backend: str, interpret: bool):
 
     # 5. at most one failure event per lane per iteration
     nf = _row(P["ft"], st["fail_ptr"])
-    fdue = alive & (nf <= t + _EPS)
+    fdue = alive & (nf <= t_eps)
     fnode = _row(P["fnode"], st["fail_ptr"])
     fk = _row(P["fkcode"], st["fail_ptr"])
     fhw = _row(P["fhw"], st["fail_ptr"])
@@ -249,13 +313,13 @@ def _iteration(st, P, backend: str, interpret: bool):
     st["iso_reason"] = jnp.where(sm, 1, st["iso_reason"])
     st["excl"] = st["excl"] | sm
     st["repair"] = jnp.where(
-        sm, t[:, None] + P["slow_iso_h"][:, None], st["repair"])
+        sm, f64_add(t, P["slow_iso_h"])[:, None], st["repair"])
     # hardware kills: node down + repair timer + setdefault isolation
     m_kill = fdue & (fk <= 1)
     hm = (m_kill & fhw)[:, None] & node_m
     st["healthy"] = st["healthy"] & ~hm
     st["repair"] = jnp.where(
-        hm, t[:, None] + P["repair_h"][:, None], st["repair"])
+        hm, f64_add(t, P["repair_h"])[:, None], st["repair"])
     newly2 = hm & (st["iso_reason"] == 0)
     st["iso_order"] = jnp.where(newly2, st["iso_ctr"][:, None],
                                 st["iso_order"])
@@ -272,7 +336,7 @@ def _iteration(st, P, backend: str, interpret: bool):
     st["u_ptr"] = st["u_ptr"] + ghit
     xf = _row(P["x_full"], st["x_ptr"])
     st["struct_until"] = jnp.where(
-        soft, jnp.maximum(st["struct_until"], t + xf),
+        soft, jnp.maximum(st["struct_until"], f64_add(t, xf)),
         st["struct_until"])
     st["x_ptr"] = st["x_ptr"] + soft
     st, flags = _fail_session(st, flags, P, ghit, fhw)
@@ -283,7 +347,7 @@ def _iteration(st, P, backend: str, interpret: bool):
     # (the numpy loop processes failures then escalations per iteration)
     nf2 = _row(P["ft"], st["fail_ptr"])
     ne = _row(P["et"], st["esc_ptr"])
-    edue = alive & (ne <= t + _EPS) & ~(nf2 <= t + _EPS)
+    edue = alive & (ne <= t_eps) & ~(nf2 <= t_eps)
     en = _row(P["enode"], st["esc_ptr"])
     ehit_node = jnp.take_along_axis(st["in_gang"],
                                     jnp.clip(en, 0, n - 1)[:, None],
@@ -295,7 +359,7 @@ def _iteration(st, P, backend: str, interpret: bool):
     st["u_ptr"] = st["u_ptr"] + ehit
     xf2 = _row(P["x_full"], st["x_ptr"])
     st["struct_until"] = jnp.where(
-        soft2, jnp.maximum(st["struct_until"], t + xf2),
+        soft2, jnp.maximum(st["struct_until"], f64_add(t, xf2)),
         st["struct_until"])
     st["x_ptr"] = st["x_ptr"] + soft2
     st, flags = _fail_session(st, flags, P, ehit, zero_b)
@@ -305,13 +369,13 @@ def _iteration(st, P, backend: str, interpret: bool):
 
     # 6. next-event horizon (same-time candidates mask to +inf; the
     # duration term keeps the min finite, exactly the numpy fallback)
-    c_pend = jnp.where(st["cur_on"], jnp.inf, st["pend"])
+    c_pend = jnp.where(st["cur_on"], _INF, st["pend"])
     c_prep = jnp.where(st["cur_on"] & ~st["cur_run"], st["prep_until"],
-                       jnp.inf)
+                       _INF)
     t_next = P["duration"]
     for c in (jnp.min(st["repair"], axis=1), c_pend, c_prep, nf2, ne2):
-        t_next = jnp.minimum(t_next, jnp.where(c <= t + _EPS, jnp.inf, c))
-    pending = (nf2 <= t + _EPS) | (ne2 <= t + _EPS)
+        t_next = jnp.minimum(t_next, jnp.where(c <= t_eps, _INF, c))
+    pending = (nf2 <= t_eps) | (ne2 <= t_eps)
     t_next = jnp.where(pending, t, t_next)
 
     # record + finalize
@@ -349,18 +413,20 @@ def _iteration(st, P, backend: str, interpret: bool):
 def wavefront_core(P, *, n_nodes: int, n_sessions: int, n_iters: int,
                    backend: str = "xla", interpret: bool = False):
     """Run the compiled wavefront over the lane tables ``P`` (the
-    ``LaneTables.device`` dict as jnp arrays, f64 floats).  Returns the
-    record stream, session gang bitmasks, integer accumulators, overflow
-    flags and the iteration count — everything the host replay needs."""
+    ``LaneTables.device`` dict as jnp arrays, every float table as the
+    int64 bit patterns of its doubles).  Returns the record stream
+    (``rec_t`` as bit patterns too), session gang bitmasks, integer
+    accumulators, overflow flags and the iteration count — everything
+    the host replay needs."""
     L = P["u"].shape[0]
     n, NS, I = n_nodes, n_sessions, n_iters
-    inf = jnp.inf
+    f64 = P["u"].dtype                 # int64 bit patterns
     st = {
-        "t": jnp.zeros(L),
+        "t": jnp.zeros(L, f64),
         "alive": P["lane_on"],
-        "pend": jnp.zeros(L),          # first attempt queued at t=0
-        "prep_until": jnp.zeros(L),
-        "struct_until": jnp.full(L, -1.0),
+        "pend": jnp.zeros(L, f64),     # first attempt queued at t=0
+        "prep_until": jnp.zeros(L, f64),
+        "struct_until": jnp.full(L, _NEG_ONE, f64),
         "cur_on": jnp.zeros(L, dtype=bool),
         "cur_run": jnp.zeros(L, dtype=bool),
         "prep_fails": jnp.zeros(L, dtype=bool),
@@ -376,7 +442,7 @@ def wavefront_core(P, *, n_nodes: int, n_sessions: int, n_iters: int,
         "healthy": jnp.ones((L, n), dtype=bool),
         "excl": jnp.zeros((L, n), dtype=bool),
         "in_gang": jnp.zeros((L, n), dtype=bool),
-        "repair": jnp.full((L, n), inf),
+        "repair": jnp.full((L, n), _INF, f64),
         "iso_reason": jnp.zeros((L, n), dtype=jnp.int8),
         "iso_order": jnp.full((L, n), _ORD_MAX, dtype=jnp.int32),
         "npart_counts": jnp.zeros((L, n), dtype=jnp.int32),
@@ -384,7 +450,7 @@ def wavefront_core(P, *, n_nodes: int, n_sessions: int, n_iters: int,
         "n_delib": jnp.zeros(L, dtype=jnp.int32),
         "n_sessions": jnp.zeros(L, dtype=jnp.int32),
         "se_gang": jnp.zeros((L, NS, n), dtype=bool),
-        "rec_t": jnp.zeros((I, L)),
+        "rec_t": jnp.zeros((I, L), f64),
         "rec_flags": jnp.zeros((I, L), dtype=jnp.int32),
         "overflow": jnp.zeros(L, dtype=bool),
         "it": jnp.int32(0),

@@ -8,6 +8,14 @@ import; smoke tests and benchmarks see the real single device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """Mesh whose axes are all ``Auto``: the model code shards through
+    ``with_sharding_constraint`` hints, which Explicit axes (the
+    ``jax.make_mesh`` default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,12 +24,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     is the HSDP replica axis (paper §3.1 Table 5)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """A trivial 1-device mesh for CPU smoke runs through the same API."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -19,6 +19,7 @@ import json
 import math
 import time
 from pathlib import Path
+from typing import Union
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config
+from repro.configs.base import ArchConfig
 from repro.core.retry import (Attempt, Chain, RetryConfig, RetryEngine,
                               RetryPolicy, chain_stats)
 from repro.core.xid import XID_TABLE
@@ -57,7 +59,8 @@ class TrainReport:
     losses: list
 
 
-def run_training(arch: str = "stablelm-3b", *, steps: int = 50,
+def run_training(arch: Union[str, ArchConfig] = "stablelm-3b", *,
+                 steps: int = 50,
                  batch: int = 2, seq: int = 128,
                  ckpt_dir: str = "/tmp/repro_ckpt",
                  fail_at: tuple = (), fail_xid: int = 94,
@@ -65,9 +68,16 @@ def run_training(arch: str = "stablelm-3b", *, steps: int = 50,
                  mtbf_h: float = 56.2, full: bool = False,
                  lr: float = 1e-3, seed: int = 0,
                  log_every: int = 10, verbose: bool = True) -> TrainReport:
-    cfg = get_config(arch)
-    if not full:
-        cfg = cfg.reduced()
+    """Train ``arch`` for ``steps`` steps under the recovery stack.
+
+    ``arch`` is a registered name (reduced to a CPU-sized config unless
+    ``full``) or an `ArchConfig`, which is trained as given."""
+    if isinstance(arch, str):
+        cfg = get_config(arch)
+        if not full:
+            cfg = cfg.reduced()
+    else:
+        cfg = arch
     opts = RunOptions(q_chunk=min(128, seq), kv_chunk=min(128, seq))
     optimizer = AdamW(lr=lr, warmup_steps=max(steps // 10, 1),
                       total_steps=steps)
@@ -75,14 +85,17 @@ def run_training(arch: str = "stablelm-3b", *, steps: int = 50,
     rng = jax.random.PRNGKey(seed)
     params = model_mod.init_params(rng, cfg)
     opt_state = optimizer.init(params)
-    train_step = jax.jit(make_train_step(cfg, opts, optimizer))
+    # the step's inputs are dead once it returns: donating them keeps one
+    # copy of the weights and optimizer moments on the device, not two
+    train_step = jax.jit(make_train_step(cfg, opts, optimizer),
+                         donate_argnums=(0, 1))
 
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=seq, seed=seed)
     stream = synthetic_stream(data_cfg, batch, seed=seed)
 
-    mgr = CheckpointManager(Path(ckpt_dir) / arch, keep=2)
+    mgr = CheckpointManager(Path(ckpt_dir) / cfg.name, keep=2)
     retry = RetryEngine(RetryConfig(policy=RetryPolicy(retry_policy)))
-    chain = Chain(task_name=f"train-{arch}")
+    chain = Chain(task_name=f"train-{cfg.name}")
 
     # Young/Daly interval in *steps*: measure delta on the first save, then
     # T_opt = sqrt(2 delta M) converted via measured step time.
@@ -161,8 +174,13 @@ def run_training(arch: str = "stablelm-3b", *, steps: int = 50,
             mgr.wait()
             last = mgr.latest_step()
             if last is not None:
-                state, _ = mgr.restore(like={"params": params,
-                                             "opt_state": opt_state})
+                # a restarted session holds no state of the failed one:
+                # drop it before loading, so the device never holds both
+                like = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                    {"params": params, "opt_state": opt_state})
+                params = opt_state = None
+                state, _ = mgr.restore(like=like)
                 params, opt_state = state["params"], state["opt_state"]
                 step = last
             else:

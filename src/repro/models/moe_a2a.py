@@ -27,16 +27,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import MoESpec
 from repro.models.layers import swiglu
 
-# jax >= 0.6 promotes shard_map to the top level (replication checking via
-# ``check_vma``); 0.4.x ships it under experimental with ``check_rep``
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _NO_CHECK = {"check_vma": False}
-else:                                     # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NO_CHECK = {"check_rep": False}
-
-
 def _local_expert_compute(xe, expert_ids, p, n_local, capacity):
     """Compute the local expert slice over received tokens.
 
@@ -134,13 +124,13 @@ def moe_ffn_a2a(x, p, spec: MoESpec, mesh, *, batch_axes=("data",),
             contrib.reshape(-1, d), mode="drop")
         return out        # home tokens are disjoint across devices
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(tok_axes, None), P(tok_axes, None), P(tok_axes, None),
                   P(model_axis, None, None), P(model_axis, None, None),
                   P(model_axis, None, None)),
         out_specs=P(tok_axes, None),
-        **_NO_CHECK)
+        check_vma=False)
     out = shard(xf, gate_idx, gate_vals.astype(xf.dtype),
                 p["w_gate"], p["w_up"], p["w_down"])
 

@@ -23,6 +23,7 @@ reports how wide they actually are.
 from __future__ import annotations
 
 import concurrent.futures
+import multiprocessing
 import os
 import time
 from dataclasses import dataclass
@@ -590,7 +591,9 @@ class SweepRunner:
 
     ``executor``: "process" (default — campaigns are CPU-bound pure Python/
     numpy), "thread", or "serial" (in-process, deterministic ordering, used
-    by tests).
+    by tests).  Under "process", campaigns with a compiled
+    ``detector_backend`` still run in this process: they call JAX, and
+    one process holds the device.
 
     ``mc_seeds``: Monte Carlo mode.  ``mc_seeds=N`` overrides ``seeds``
     with ``range(N)`` and routes every scenario through one
@@ -642,17 +645,33 @@ class SweepRunner:
         tasks = [(sc.to_dict(), seed)
                  for sc in self.scenarios for seed in self.seeds]
         t0 = time.perf_counter()
-        if self.executor == "serial":
-            raw = [run_campaign(d, s) for d, s in tasks]
+        # one process holds the device: a campaign whose detector runs a
+        # compiled backend calls JAX, so it stays in this process, and
+        # only numpy-only campaigns go to pooled children
+        def in_parent(spec: dict) -> bool:
+            return self.executor == "serial" or (
+                self.executor == "process"
+                and spec["detector_backend"] != "numpy")
+        local = [t for t in tasks if in_parent(t[0])]
+        pooled = [t for t in tasks if not in_parent(t[0])]
+        if not pooled:
+            raw = [run_campaign(d, s) for d, s in local]
         else:
-            pool_cls = concurrent.futures.ProcessPoolExecutor \
-                if self.executor == "process" \
-                else concurrent.futures.ThreadPoolExecutor
-            workers = self.max_workers or min(len(tasks),
+            workers = self.max_workers or min(len(pooled),
                                               os.cpu_count() or 1)
-            with pool_cls(max_workers=workers) as pool:
-                futs = [pool.submit(run_campaign, d, s) for d, s in tasks]
-                raw = [f.result() for f in futs]
+            if self.executor == "process":
+                # spawned children start from a fresh interpreter, never
+                # from a fork of a parent whose JAX runtime may be live
+                pool = concurrent.futures.ProcessPoolExecutor(
+                    max_workers=workers,
+                    mp_context=multiprocessing.get_context("spawn"))
+            else:
+                pool = concurrent.futures.ThreadPoolExecutor(
+                    max_workers=workers)
+            with pool:
+                futs = [pool.submit(run_campaign, d, s) for d, s in pooled]
+                raw = [run_campaign(d, s) for d, s in local]
+                raw += [f.result() for f in futs]
         wall = time.perf_counter() - t0
         order = {sc.name: i for i, sc in enumerate(self.scenarios)}
         outcomes = sorted(
@@ -670,14 +689,9 @@ class SweepRunner:
         backend = self.wavefront_backend
         if backend == "numpy":
             return {}
-        try:
-            from repro.kernels.common import WAVEFRONT_MIN_SEEDS
-            from repro.kernels.wavefront import compiled_eligible
-            from repro.kernels.wavefront.ops import run_findings_grid
-        except ImportError:              # no jax: auto degrades to numpy
-            if backend != "auto":
-                raise
-            return {}
+        from repro.kernels.common import WAVEFRONT_MIN_SEEDS
+        from repro.kernels.wavefront import compiled_eligible
+        from repro.kernels.wavefront.ops import run_findings_grid
         cfgs = [sc.to_campaign_config(0) for sc in self.scenarios]
         groups: Dict[int, List[int]] = {}
         for i, cfg in enumerate(cfgs):

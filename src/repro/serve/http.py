@@ -27,6 +27,7 @@ import json
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from repro.compile_cache import use_compile_cache
 from repro.ops.scenario import get_scenario
 from repro.serve.service import (ServiceConfig, WhatIfService,
                                  scenario_from_request)
@@ -149,6 +150,7 @@ def main(argv: Optional[list] = None) -> None:
     ap.add_argument("--verbose", action="store_true",
                     help="log one line per request")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = ServiceConfig(window_s=args.window_ms / 1e3,
                         coalesce=args.window_ms > 0,

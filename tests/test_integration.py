@@ -25,6 +25,24 @@ def test_trainer_recovers_from_injected_xid(tmp_path):
     assert all(r % max(24 // 5, 5) == 0 for r in rep.restore_steps)
 
 
+def test_trainer_takes_arch_config_as_given(tmp_path):
+    """An `ArchConfig` trains as given (no reduction), and the restore
+    after an injected XID lands on the last checkpoint."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.launch.train import run_training
+
+    cfg = dataclasses.replace(get_config("stablelm-3b").reduced(),
+                              n_periods=1)
+    rep = run_training(cfg, steps=12, batch=2, seq=32,
+                       ckpt_dir=str(tmp_path), fail_at=(7,), fail_xid=94,
+                       verbose=False)
+    assert rep.steps_done == 12 and rep.restore_steps == [5]
+    assert np.isfinite(rep.final_loss)
+    assert (tmp_path / cfg.name).is_dir()
+
+
 def test_trainer_xid79_stops_for_operator(tmp_path):
     """RESTART_BM (XID 79) halts auto-retry — operator action required."""
     from repro.launch.train import run_training

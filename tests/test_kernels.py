@@ -166,3 +166,17 @@ def test_ckpt_pack_detects_corruption():
     _, chk1 = ckpt_pack(x2, block=512, interpret=True)
     assert chk0[0] != chk1[0]
     assert bool(jnp.all(chk0[1:] == chk1[1:]))
+
+
+def test_ckpt_pack_row_padding_matches_reference():
+    """Block counts off the kernel's row tile pad and slice back: the
+    outputs keep the caller's block count and equal the reference."""
+    from repro.kernels.ckpt_pack.kernel import ckpt_pack_blocks
+    from repro.kernels.ckpt_pack.ref import ckpt_pack_blocks_ref
+
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(13, 256)),
+                    jnp.float32)
+    y, chk = ckpt_pack_blocks(x, interpret=True)
+    y_r, chk_r = ckpt_pack_blocks_ref(x)
+    assert y.shape == (13, 256) and chk.shape == (13, 1)
+    assert bool(jnp.all(y == y_r)) and bool(jnp.all(chk == chk_r))

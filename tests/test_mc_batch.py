@@ -355,6 +355,56 @@ def test_compiled_wavefront_infra_band_parity():
                for r in refs for s in r.sessions), "no escalation crash"
 
 
+def _f64_bits(x):
+    return np.asarray(x, dtype=np.float64).view(np.int64)
+
+
+def test_device_add_is_ieee_round_half_even():
+    """The device core adds doubles as int64 bit patterns (a TPU does not
+    hold a double as f64 bit for bit); the result equals numpy's add bit for
+    bit: clocks plus delays, exact ties, carries into the next binade,
+    subnormals, zeros and +inf."""
+    import jax
+    from repro.kernels.wavefront.ref import f64_add
+    rng = np.random.default_rng(0)
+    n = 100_000
+    scale = 2.0 ** rng.integers(-60, 60, n)
+    a = np.concatenate([
+        rng.uniform(0, 2000, n), rng.exponential(3.0, n),
+        rng.uniform(0, 1, n) * scale,
+        [0.0, 0.0, 1.0, 1.0, 1.0, 2.0 - 2.0 ** -52, 5e-324, np.inf, 3.0]])
+    b = np.concatenate([
+        rng.exponential(1.0, n), rng.uniform(0, 1e-9, n),
+        rng.uniform(0, 1, n) * scale[::-1],
+        [0.0, 1e-12, 2.0 ** -53, 3 * 2.0 ** -53, 2.0 ** -52, 2.0 ** -52,
+         5e-324, 1.0, np.inf]])
+    with jax.enable_x64(True):
+        got = np.asarray(f64_add(_f64_bits(a), _f64_bits(b)))
+        swapped = np.asarray(f64_add(_f64_bits(b), _f64_bits(a)))
+    assert np.array_equal(got, _f64_bits(a + b))
+    assert np.array_equal(swapped, got)
+
+
+def test_device_night_matches_manual_delay_rule():
+    """The off-hours test on bit patterns agrees with the scalar engine's
+    ``t % 24`` / ``t // 24`` rule, including clocks exactly on and one
+    ulp either side of the 8:00 and 20:00 boundaries and the weekend."""
+    import jax
+    from repro.kernels.wavefront.ref import f64_night
+    rng = np.random.default_rng(1)
+    edges = np.array([24.0 * d + h for d in range(14)
+                      for h in (0.0, 8.0, 20.0, 21.0)])
+    t = np.concatenate([
+        rng.uniform(0, 2000, 50_000), np.arange(0, 400, 0.25), edges,
+        np.nextafter(edges, np.inf), np.nextafter(edges[1:], 0.0),
+        [0.0, 5e-324, 1e-12, 0.5, 1.0]])
+    expect = np.array([(int(x // 24.0) % 7 >= 5) or (x % 24.0 < 8)
+                       or (x % 24.0 > 20) for x in t])
+    with jax.enable_x64(True):
+        got = np.asarray(f64_night(_f64_bits(t)))
+    assert np.array_equal(got, expect)
+
+
 def test_compiled_backend_rejects_ineligible_config():
     """Explicitly forcing the device core on a control-plane config is a
     hard error; auto silently stays on the numpy wavefront."""

@@ -19,7 +19,9 @@ from repro.configs.base import MoESpec
 from repro.models.moe import init_moe, moe_ffn
 from repro.models.moe_a2a import moe_ffn_a2a
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 spec = MoESpec(n_experts=8, top_k=2, d_expert=16, n_shared=1)
 p = init_moe(jax.random.PRNGKey(0), 32, spec, jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))

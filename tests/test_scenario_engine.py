@@ -121,6 +121,34 @@ def test_sweep_deterministic_across_runs_and_executors():
     assert len(a.outcomes) == 4
 
 
+def test_process_pool_gets_numpy_campaigns_only(monkeypatch):
+    """A compiled detector backend calls JAX, so its campaigns run in the
+    parent; the pool (spawned children) sees numpy-only campaigns."""
+    import concurrent.futures
+
+    submitted = []
+
+    class RecordingPool(concurrent.futures.ThreadPoolExecutor):
+        def __init__(self, max_workers=None, mp_context=None):
+            assert mp_context.get_start_method() == "spawn"
+            super().__init__(max_workers=max_workers)
+
+        def submit(self, fn, spec, seed):
+            submitted.append(spec["name"])
+            return super().submit(fn, spec, seed)
+
+    monkeypatch.setattr(concurrent.futures, "ProcessPoolExecutor",
+                        RecordingPool)
+    scs = [get_scenario("paper-faithful").replace(duration_days=1.0),
+           get_scenario("proactive").replace(
+               name="proactive-xla", duration_days=0.5,
+               telemetry_pad_metrics=0, detector_backend="xla")]
+    res = SweepRunner(scs, seeds=(0,), executor="process").run()
+    assert submitted == ["paper-faithful"]
+    assert [o.scenario for o in res.outcomes] == [
+        "paper-faithful", "proactive-xla"]
+
+
 def test_sweep_aggregate_and_report(tmp_path):
     scs = [get_scenario(n).replace(duration_days=5.0)
            for n in ("paper-faithful", "smart-retry")]
