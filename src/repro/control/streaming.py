@@ -43,6 +43,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.precursor import Alarm, DetectorConfig
 
 
@@ -252,28 +253,34 @@ class StreamingDetector:
         if len(ts) == 0 or not names:
             return []
         T, n = np.asarray(values[names[0]]).shape
-        active = self._activity(values, (T, n))
-
-        if self.backend == "numpy" or not _worth_compiling(
-                1, len(names), T, n):
-            hit = self._hit_pass_numpy(values, names, active, T, n)
-            streak = self._span_streak(hit, T, n)
-        else:
-            # fused compiled pass; the pre-span carry feeds the scan
-            carry = np.zeros((1, n), dtype=np.int32) \
-                if self._streak is None \
-                else self._streak[None].astype(np.int32)
-            hit, streak = self._detect_compiled(
-                [values], names, active[None], carry, cfg, self.backend)
-            hit, streak = hit[0], streak[0]
-        self._streak = streak[-1].copy()
+        with tracing.span("detector.pass1"):
+            active = self._activity(values, (T, n))
+            tracing.count("detector.seed_ticks", T)
+            if self.backend == "numpy" or not _worth_compiling(
+                    1, len(names), T, n):
+                hit = self._hit_pass_numpy(values, names, active, T, n)
+                streak = self._span_streak(hit, T, n)
+            else:
+                # fused compiled pass; the pre-span carry feeds the scan
+                tracing.count("detector.compiled_seed_ticks", T)
+                carry = np.zeros((1, n), dtype=np.int32) \
+                    if self._streak is None \
+                    else self._streak[None].astype(np.int32)
+                with tracing.span("detector.device"):
+                    hit, streak = self._detect_compiled(
+                        [values], names, active[None], carry, cfg,
+                        self.backend)
+                hit, streak = hit[0], streak[0]
+            self._streak = streak[-1].copy()
 
         rows, nodes = np.nonzero(streak == cfg.persistence)
         if len(rows) == 0:
             self._tick_offset += T
             return []
 
-        alarms = self._attribute(ts, values, names, active, hit, rows, nodes)
+        with tracing.span("detector.attribute"):
+            alarms = self._attribute(ts, values, names, active, hit, rows,
+                                     nodes)
         self._tick_offset += T
         self.n_alarms += len(alarms)
         return alarms
@@ -362,7 +369,28 @@ class StreamingDetector:
             return [d.push(t, v) for d, t, v in
                     zip(detectors, ts_list, values_list)]
         T, n = np.asarray(values_list[0][names[0]]).shape
+        with tracing.span("detector.pass1"):
+            active, hit, streak = cls._pass1_group(
+                detectors, values_list, names, cfg, backend, S, T, n)
 
+        out: List[List[Alarm]] = []
+        with tracing.span("detector.attribute"):
+            for i, d in enumerate(detectors):
+                d._streak = streak[i, -1].copy()
+                rows, nodes = np.nonzero(streak[i] == cfg.persistence)
+                alarms = [] if len(rows) == 0 else d._attribute(
+                    ts_list[i], values_list[i], names, active[i], hit[i],
+                    rows, nodes)
+                d._tick_offset += T
+                d.n_alarms += len(alarms)
+                out.append(alarms)
+        return out
+
+    @classmethod
+    def _pass1_group(cls, detectors, values_list, names, cfg, backend,
+                     S: int, T: int, n: int):
+        """Activity, pass 1 and the streak scan of ``push_group``:
+        (active, hit, streak), each (S, T, n)."""
         # activity with per-detector carry, stacked to (S, T, n)
         if cfg.activity_metric in values_list[0]:
             act_now = np.stack(
@@ -379,6 +407,7 @@ class StreamingDetector:
             for d in detectors:
                 d._prev_act = active[0, -1:].copy()
 
+        tracing.count("detector.seed_ticks", S * T)
         if backend == "numpy" or not _worth_compiling(S, len(names), T, n):
             # pass 1 on (S, B, T, n) blocks; same per-seed dtype grouping
             # and block budget as the scalar path (the grouping never
@@ -408,20 +437,11 @@ class StreamingDetector:
             streak += np.where(over & (last_reset == 0),
                                carry[:, None, :], 0)
         else:
+            tracing.count("detector.compiled_seed_ticks", S * T)
             carry = np.stack(
                 [d._streak.astype(np.int32) if d._streak is not None
                  else np.zeros(n, dtype=np.int32) for d in detectors])
-            hit, streak = cls._detect_compiled(
-                values_list, names, active, carry, cfg, backend)
-
-        out: List[List[Alarm]] = []
-        for i, d in enumerate(detectors):
-            d._streak = streak[i, -1].copy()
-            rows, nodes = np.nonzero(streak[i] == cfg.persistence)
-            alarms = [] if len(rows) == 0 else d._attribute(
-                ts_list[i], values_list[i], names, active[i], hit[i],
-                rows, nodes)
-            d._tick_offset += T
-            d.n_alarms += len(alarms)
-            out.append(alarms)
-        return out
+            with tracing.span("detector.device"):
+                hit, streak = cls._detect_compiled(
+                    values_list, names, active, carry, cfg, backend)
+        return active, hit, streak

@@ -41,6 +41,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.cluster import (RNG_STREAM_MANUAL, RNG_STREAM_STRUCT,
                                 TICK_H, _MAX_SPAN_TICKS, CampaignConfig,
                                 CampaignResult, ClusterSim)
@@ -346,7 +347,8 @@ class BatchedCampaignEngine:
                 return run_findings_compiled(self.cfg, seeds,
                                              backend=backend)
         B = self._simulate(seeds, materialize=False)
-        return [self._findings(B, i) for i in range(B.S)]
+        with tracing.span("engine.findings"):
+            return [self._findings(B, i) for i in range(B.S)]
 
     # -- setup --------------------------------------------------------------
 
@@ -838,29 +840,33 @@ class BatchedCampaignEngine:
 
         while emitting:
             chunk: Dict[int, tuple] = {}
-            for s in emitting:
-                k0 = int(B.next_k[s])
-                k1 = min(k0 + B.max_chunk, int(k_end[s]))
-                ts = np.arange(k0, k1) * TICK_H
-                training, loading, down_row, running = rows_cache[s]
-                if running:
-                    phase = np.mod(ts - B.last_ckpt[s],
-                                   cfg.checkpoint_interval_h)
-                    ckpt_mask = (phase < cfg.checkpoint_save_s / 3600.0)
-                    ckpt = ckpt_mask[:, None] * training[None, :]
-                else:
-                    ckpt = None
-                batch = NodeStateBatch.constant(
-                    len(ts), B.n, training=training, loading=loading,
-                    checkpointing=ckpt, down=down_row)
-                sigs = B.pending_sigs[s]
-                rows = [(k - k0, ev) for k, ev in sigs if k0 <= k < k1]
-                B.pending_sigs[s] = [(k, ev) for k, ev in sigs if k >= k1]
-                snap = B.exporters[s].tick_batch(ts, batch, rows)
-                if B.stores[s] is not None:
-                    B.stores[s].append_batch(ts, snap)
-                B.next_k[s] = k1
-                chunk[s] = (ts, snap)
+            with tracing.span("engine.telemetry"):
+                for s in emitting:
+                    k0 = int(B.next_k[s])
+                    k1 = min(k0 + B.max_chunk, int(k_end[s]))
+                    ts = np.arange(k0, k1) * TICK_H
+                    training, loading, down_row, running = rows_cache[s]
+                    if running:
+                        phase = np.mod(ts - B.last_ckpt[s],
+                                       cfg.checkpoint_interval_h)
+                        ckpt_mask = (phase
+                                     < cfg.checkpoint_save_s / 3600.0)
+                        ckpt = ckpt_mask[:, None] * training[None, :]
+                    else:
+                        ckpt = None
+                    batch = NodeStateBatch.constant(
+                        len(ts), B.n, training=training, loading=loading,
+                        checkpointing=ckpt, down=down_row)
+                    sigs = B.pending_sigs[s]
+                    rows = [(k - k0, ev) for k, ev in sigs
+                            if k0 <= k < k1]
+                    B.pending_sigs[s] = [(k, ev) for k, ev in sigs
+                                         if k >= k1]
+                    snap = B.exporters[s].tick_batch(ts, batch, rows)
+                    if B.stores[s] is not None:
+                        B.stores[s].append_batch(ts, snap)
+                    B.next_k[s] = k1
+                    chunk[s] = (ts, snap)
 
             # group-scan control seeds by chunk length; apply per seed
             ctl = [s for s in emitting if B.planes[s] is not None]
@@ -873,18 +879,20 @@ class BatchedCampaignEngine:
                     [B.planes[s].detector for s in group],
                     [chunk[s][0] for s in group],
                     [chunk[s][1] for s in group])
-                for s, alarms in zip(group, alarm_lists):
-                    plane = B.planes[s]
-                    if plane.log is not None:
-                        # log channel: same per-chunk fusion point as the
-                        # scalar `ControlPlane.on_chunk` — chunk windows
-                        # are mirrored, so the emitter's draws line up
-                        alarms = plane.fuse_alarms(
-                            alarms, plane.scan_logs(chunk[s][0],
-                                                    B.views[s]))
-                    if plane.apply_alarms(alarms, B.views[s]):
-                        t_stop[s] = float(B.next_k[s]) * TICK_H
-                        halted.add(s)
+                with tracing.span("control.apply"):
+                    for s, alarms in zip(group, alarm_lists):
+                        plane = B.planes[s]
+                        if plane.log is not None:
+                            # log channel: same per-chunk fusion point as
+                            # the scalar `ControlPlane.on_chunk` — chunk
+                            # windows are mirrored, so the emitter's draws
+                            # line up
+                            alarms = plane.fuse_alarms(
+                                alarms, plane.scan_logs(chunk[s][0],
+                                                        B.views[s]))
+                        if plane.apply_alarms(alarms, B.views[s]):
+                            t_stop[s] = float(B.next_k[s]) * TICK_H
+                            halted.add(s)
             emitting = [s for s in emitting
                         if s not in halted and B.next_k[s] < k_end[s]]
         return t_stop
@@ -894,14 +902,15 @@ class BatchedCampaignEngine:
     def _simulate(self, seeds: Sequence[int],
                   materialize: bool) -> _Batch:
         cfg = self.cfg
-        injector = FailureInjector(
-            n_nodes=cfg.n_nodes, mtbf_h=cfg.mtbf_h,
-            hot_fraction=cfg.hot_fraction, hot_weight=cfg.hot_weight,
-            kind_weights=cfg.kind_weights,
-            topology_fanout=cfg.topology_fanout, seed=cfg.seed)
-        fails = injector.sample_batch(cfg.duration_h, seeds)
-        B = _Batch(cfg, seeds, fails, materialize)
-        self._setup_telemetry(B)
+        with tracing.span("engine.draws"):
+            injector = FailureInjector(
+                n_nodes=cfg.n_nodes, mtbf_h=cfg.mtbf_h,
+                hot_fraction=cfg.hot_fraction, hot_weight=cfg.hot_weight,
+                kind_weights=cfg.kind_weights,
+                topology_fanout=cfg.topology_fanout, seed=cfg.seed)
+            fails = injector.sample_batch(cfg.duration_h, seeds)
+            B = _Batch(cfg, seeds, fails, materialize)
+            self._setup_telemetry(B)
         telemetry = bool(B.tel_seeds)
         duration = cfg.duration_h
         interval = cfg.checkpoint_interval_h
@@ -916,8 +925,9 @@ class BatchedCampaignEngine:
         # design; silence the FPE flag once for the whole run
         err_state = np.seterr(invalid="ignore")
         try:
-            self._wavefront(B, cand, rep_min, ftimes, foffs, duration,
-                            interval, telemetry)
+            with tracing.span("engine.events"):
+                self._wavefront(B, cand, rep_min, ftimes, foffs, duration,
+                                interval, telemetry)
         finally:
             np.seterr(**err_state)
         return B
@@ -1225,9 +1235,10 @@ def run_findings_stacked(configs: Sequence[CampaignConfig],
         from repro.kernels.wavefront import compiled_eligible
         from repro.kernels.wavefront.ops import run_findings_grid
         groups: Dict[int, List[int]] = {}
-        for i, cfg in enumerate(configs):
-            if compiled_eligible(ClusterSim(cfg).cfg):
-                groups.setdefault(cfg.n_nodes, []).append(i)
+        with tracing.span("stacked.resolve"):
+            for i, cfg in enumerate(configs):
+                if compiled_eligible(ClusterSim(cfg).cfg):
+                    groups.setdefault(cfg.n_nodes, []).append(i)
         dev = "xla" if wavefront_backend == "auto" else wavefront_backend
         for idxs in groups.values():
             if wavefront_backend == "auto" \

@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.cluster import CampaignConfig, ClusterSim
 from repro.core.failures import (FailureInjector, degraded_overlap_h,
                                  has_correlated_band)
@@ -108,13 +109,16 @@ def _run_core(tables: LaneTables, backend: str, interpret: bool):
     import jax
     import jax.numpy as jnp
     with jax.enable_x64(True):
-        P = {k: jnp.asarray(v) for k, v in device_tables(tables).items()}
-        out = wavefront_core(
-            P, n_nodes=tables.n_nodes,
-            n_sessions=tables.caps.n_sessions,
-            n_iters=tables.caps.n_iters,
-            backend=backend, interpret=interpret)
-        host = {k: np.asarray(v) for k, v in out.items()}
+        with tracing.span("grid.upload"):
+            P = {k: jnp.asarray(v)
+                 for k, v in device_tables(tables).items()}
+        with tracing.span("grid.run"):          # dispatch through fetch
+            out = wavefront_core(
+                P, n_nodes=tables.n_nodes,
+                n_sessions=tables.caps.n_sessions,
+                n_iters=tables.caps.n_iters,
+                backend=backend, interpret=interpret)
+            host = {k: np.asarray(v) for k, v in out.items()}
     host["rec_t"] = host["rec_t"].view(np.float64)
     return host
 
@@ -123,7 +127,9 @@ def _run_with_caps(build, backend: str, interpret: bool):
     """build(caps) -> LaneTables; rerun with doubled caps until no lane
     overflows (results are never read from an overflowed pass)."""
     caps = None
-    for _ in range(_MAX_CAP_RETRIES):
+    for attempt in range(_MAX_CAP_RETRIES):
+        if attempt:
+            tracing.count("grid.cap_reruns")
         tables = build(caps)
         caps = tables.caps
         host = _run_core(tables, backend, interpret)
@@ -343,35 +349,40 @@ def run_findings_grid(configs: Sequence[CampaignConfig],
     if interpret is None:
         interpret = not on_tpu()
     resolved = []
-    for cfg in configs:
-        base = ClusterSim(cfg)
-        rcfg = base.cfg
-        if not compiled_eligible(rcfg):
-            raise ValueError(
-                "run_findings_grid covers control-free campaigns only "
-                "(telemetry off, control None, no correlated fault band)")
-        injector = FailureInjector(
-            n_nodes=rcfg.n_nodes, mtbf_h=rcfg.mtbf_h,
-            hot_fraction=rcfg.hot_fraction, hot_weight=rcfg.hot_weight,
-            kind_weights=rcfg.kind_weights,
-            topology_fanout=rcfg.topology_fanout, seed=rcfg.seed)
-        fails = injector.sample_batch(rcfg.duration_h, seeds)
-        resolved.append((rcfg, fails))
+    with tracing.span("grid.draws"):
+        for cfg in configs:
+            base = ClusterSim(cfg)
+            rcfg = base.cfg
+            if not compiled_eligible(rcfg):
+                raise ValueError(
+                    "run_findings_grid covers control-free campaigns only "
+                    "(telemetry off, control None, no correlated fault "
+                    "band)")
+            injector = FailureInjector(
+                n_nodes=rcfg.n_nodes, mtbf_h=rcfg.mtbf_h,
+                hot_fraction=rcfg.hot_fraction, hot_weight=rcfg.hot_weight,
+                kind_weights=rcfg.kind_weights,
+                topology_fanout=rcfg.topology_fanout, seed=rcfg.seed)
+            fails = injector.sample_batch(rcfg.duration_h, seeds)
+            resolved.append((rcfg, fails))
 
     def build(caps_in):
-        blocks = [build_lane_tables(rcfg, fails, seeds, caps=caps_in)
-                  for rcfg, fails in resolved]
-        return pad_lanes_pow2(concat_lane_tables(blocks))
+        with tracing.span("grid.tapes"):
+            blocks = [build_lane_tables(rcfg, fails, seeds, caps=caps_in)
+                      for rcfg, fails in resolved]
+            return pad_lanes_pow2(concat_lane_tables(blocks))
 
     first = build(caps)
     tables, host = _run_with_caps(
         lambda c: first if c is None else build(c), backend, interpret)
-    R = _replay(tables, host)
+    with tracing.span("grid.replay"):
+        R = _replay(tables, host)
     S = len(seeds)
     out: List[List[dict]] = []
-    for g in range(len(configs)):
-        out.append([_lane_findings(tables, host, R, g * S + s)
-                    for s in range(S)])
+    with tracing.span("grid.findings"):
+        for g in range(len(configs)):
+            out.append([_lane_findings(tables, host, R, g * S + s)
+                        for s in range(S)])
     return out
 
 

@@ -19,6 +19,8 @@ import time
 from concurrent.futures import Future
 from typing import Any, Callable, Dict, List, Tuple
 
+from repro import tracing
+
 __all__ = ["Coalescer"]
 
 
@@ -36,6 +38,10 @@ class Coalescer:
       request of a window lands (10-50 ms trades latency for batching).
     * ``max_batch`` — dispatch early once this many requests are queued
       (bounds worst-case batch latency under a thundering herd).
+
+    Each request's queue wait runs from ``submit`` to the dispatch of
+    its batch: the window, plus any pass still running when it arrived.
+    ``stats()`` reports their count, total and maximum.
     """
 
     def __init__(self, runner: Callable[[List[Tuple[str, Any]]],
@@ -47,13 +53,16 @@ class Coalescer:
         self.window_s = float(window_s)
         self.max_batch = int(max_batch)
         self._cv = threading.Condition()
-        self._queue: List[Tuple[str, Any, Future]] = []
+        self._queue: List[Tuple[str, Any, Future, float]] = []
         self._closed = False
         # stats (read without the lock: monotone counters, display only)
         self.n_requests = 0
         self.n_deduped = 0
         self.n_windows = 0
         self.n_dispatched = 0
+        self.n_waits = 0
+        self.wait_total_s = 0.0
+        self.wait_max_s = 0.0
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="whatif-coalescer")
         self._thread.start()
@@ -65,7 +74,7 @@ class Coalescer:
         with self._cv:
             if self._closed:
                 raise RuntimeError("coalescer is closed")
-            self._queue.append((key, payload, fut))
+            self._queue.append((key, payload, fut, time.monotonic()))
             self.n_requests += 1
             self._cv.notify()
         return fut
@@ -89,30 +98,37 @@ class Coalescer:
                     return
                 # first request opens the window; keep collecting until
                 # the deadline or the early-dispatch threshold
-                deadline = time.monotonic() + self.window_s
-                while len(self._queue) < self.max_batch:
-                    left = deadline - time.monotonic()
-                    if left <= 0 or self._closed:
-                        break
-                    self._cv.wait(timeout=left)
+                with tracing.span("serve.queue_wait"):
+                    deadline = time.monotonic() + self.window_s
+                    while len(self._queue) < self.max_batch:
+                        left = deadline - time.monotonic()
+                        if left <= 0 or self._closed:
+                            break
+                        self._cv.wait(timeout=left)
                 batch, self._queue = self._queue, []
             self._dispatch(batch)
 
-    def _dispatch(self, batch: List[Tuple[str, Any, Future]]) -> None:
+    def _dispatch(self, batch: List[Tuple[str, Any, Future, float]]
+                  ) -> None:
+        now = time.monotonic()
+        waits = [now - t for *_, t in batch]
         distinct: "Dict[str, Any]" = {}
-        for key, payload, _ in batch:
+        for key, payload, *_ in batch:
             distinct.setdefault(key, payload)
+        self.n_waits += len(waits)
+        self.wait_total_s += sum(waits)
+        self.wait_max_s = max(self.wait_max_s, max(waits))
         self.n_windows += 1
         self.n_dispatched += len(distinct)
         self.n_deduped += len(batch) - len(distinct)
         try:
             results = self.runner(list(distinct.items()))
         except BaseException as e:                 # noqa: BLE001
-            for _, _, fut in batch:
+            for _, _, fut, _ in batch:
                 if not fut.done():
                     fut.set_exception(e)
             return
-        for key, _, fut in batch:
+        for key, _, fut, _ in batch:
             if fut.done():
                 continue
             if key in results:
@@ -124,4 +140,7 @@ class Coalescer:
     def stats(self) -> dict:
         return {"requests": self.n_requests, "windows": self.n_windows,
                 "dispatched": self.n_dispatched, "deduped": self.n_deduped,
+                "queue_waits": self.n_waits,
+                "queue_wait_total_s": self.wait_total_s,
+                "queue_wait_max_s": self.wait_max_s,
                 "window_s": self.window_s, "max_batch": self.max_batch}

@@ -11,7 +11,8 @@ tier-1 stays hermetic) exposing the service core:
   grid size, error bound), or ``{"surface": null}`` when none is built;
 * ``GET /healthz`` — liveness;
 * ``GET /stats`` — queries, cache hit/miss/eviction counts, coalescer
-  window/dedup counters, engine passes, uptime.
+  window/dedup counters and queue waits (count, total, maximum), engine
+  passes, uptime.
 
 Run it:
 

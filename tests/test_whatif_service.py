@@ -230,6 +230,40 @@ def test_coalescer_windows_and_dedup():
     assert (s["requests"], s["dispatched"], s["deduped"]) == (5, 2, 3)
 
 
+def test_coalescer_queue_waits_bounded_by_window_plus_one_pass():
+    window_s, pass_s = 0.03, 0.2
+    passes = []
+
+    def runner(batch):
+        t0 = time.monotonic()
+        time.sleep(pass_s)
+        passes.append(time.monotonic() - t0)
+        return {k: k for k, _ in batch}
+
+    co = Coalescer(runner, window_s=window_s)
+    futs = []
+    threads = [threading.Thread(
+        target=lambda i=i: futs.append(co.submit(f"k{i % 5}", None)))
+        for i in range(24)]
+    try:
+        for i, th in enumerate(threads):
+            th.start()
+            if i % 6 == 5:              # later arrivals land mid-pass
+                time.sleep(0.02)
+        for th in threads:
+            th.join(timeout=5)
+            assert not th.is_alive()
+        for f in futs:
+            f.result(timeout=5)
+    finally:
+        co.close()
+    s = co.stats()
+    assert s["queue_waits"] == 24 and s["windows"] >= 2
+    assert s["queue_wait_total_s"] >= 0.0
+    assert 0.0 <= s["queue_wait_max_s"] <= window_s + max(passes) + 0.1
+    assert s["queue_wait_total_s"] <= 24 * s["queue_wait_max_s"]
+
+
 def test_coalescer_runner_error_fails_all_futures():
     def runner(batch):
         raise RuntimeError("engine exploded")
