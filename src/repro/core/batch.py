@@ -37,6 +37,9 @@ for the paper's F1-F4 findings routine instead of a batch job.
 """
 from __future__ import annotations
 
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -65,6 +68,39 @@ __all__ = ["BatchedCampaignEngine", "run_findings_stacked"]
 # hot-loop lookup: XID -> is-hardware (mirrors FailureEvent.is_hardware)
 _XID_HW = {x: meta.hardware for x, meta in XID_TABLE.items()}
 _NAN = float("nan")
+
+# the process's thread pool for concurrent telemetry chunks, made on
+# first use (`_telemetry_pool`) and kept across passes
+_pool: Optional[ThreadPoolExecutor] = None
+_pool_lock = threading.Lock()
+
+
+def _telemetry_pool() -> ThreadPoolExecutor:
+    """``os.cpu_count()`` threads, started as rounds need them: a round
+    of k chunks runs on min(k, cpu_count) of them."""
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(max_workers=os.cpu_count() or 1,
+                                       thread_name_prefix="repro-telemetry")
+        return _pool
+
+
+def _tick_chunks(jobs: Sequence[tuple]) -> list:
+    """``exporter.tick_batch(ts, batch, rows)`` for each job of one
+    round, in job order.  A lone chunk runs inline; several run on the
+    pool at once (numpy's generators and ufunc loops release the GIL).
+    Each job owns its exporter, and with it its rng stream and remap
+    counters, so every value is bit-identical to the serial calls.  All
+    jobs finish before a worker's exception is re-raised here."""
+    if len(jobs) == 1:
+        exp, ts, batch, rows = jobs[0]
+        return [exp.tick_batch(ts, batch, rows)]
+    pool = _telemetry_pool()
+    futures = [pool.submit(exp.tick_batch, ts, batch, rows)
+               for exp, ts, batch, rows in jobs]
+    wait(futures)
+    return [f.result() for f in futures]
 
 
 # ---------------------------------------------------------------------------
@@ -813,8 +849,9 @@ class BatchedCampaignEngine:
     def _emit(self, B: _Batch, t_next: np.ndarray):
         """Emit every telemetry seed's constant-state span up to its own
         ``t_next``, mirroring `_TelemetryBatcher.emit` chunk for chunk.
-        Chunks are generated per seed (each exporter owns its rng stream)
-        but scanned through the streaming detector in same-shape groups —
+        In each round the emitting seeds' chunks are generated at once
+        (`_tick_chunks`: each exporter owns its rng stream), then
+        scanned through the streaming detector in same-shape groups —
         one stacked pass per group.  A drain-grade alarm truncates that
         seed's span at the chunk boundary (returned in ``t_stop``)."""
         cfg = self.cfg
@@ -841,6 +878,7 @@ class BatchedCampaignEngine:
         while emitting:
             chunk: Dict[int, tuple] = {}
             with tracing.span("engine.telemetry"):
+                jobs = []
                 for s in emitting:
                     k0 = int(B.next_k[s])
                     k1 = min(k0 + B.max_chunk, int(k_end[s]))
@@ -862,11 +900,16 @@ class BatchedCampaignEngine:
                             if k0 <= k < k1]
                     B.pending_sigs[s] = [(k, ev) for k, ev in sigs
                                          if k >= k1]
-                    snap = B.exporters[s].tick_batch(ts, batch, rows)
+                    jobs.append((B.exporters[s], ts, batch, rows))
+                snaps = _tick_chunks(jobs)
+                for s, (_, ts, _, _), snap in zip(emitting, jobs, snaps):
                     if B.stores[s] is not None:
                         B.stores[s].append_batch(ts, snap)
-                    B.next_k[s] = k1
+                    B.next_k[s] += len(ts)
                     chunk[s] = (ts, snap)
+                lengths = [len(job[1]) for job in jobs]
+                tracing.count("engine.telemetry_ticks", sum(lengths))
+                tracing.count("engine.telemetry_path_ticks", max(lengths))
 
             # group-scan control seeds by chunk length; apply per seed
             ctl = [s for s in emitting if B.planes[s] is not None]

@@ -10,19 +10,23 @@ is exercised across retry policies, the proactive control plane
 (urgent saves + counterfactual ledger) and executed predictive drains.
 """
 import dataclasses
+import sys
 
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.control.policy import ControlConfig
 from repro.control.streaming import StreamingDetector
+from repro.core import batch as batch_mod
 from repro.core.batch import BatchedCampaignEngine
-from repro.core.cluster import CampaignConfig, ClusterSim
-from repro.core.failures import FailureInjector
+from repro.core.cluster import TICK_H, CampaignConfig, ClusterSim
+from repro.core.failures import FailureEvent, FailureInjector
 from repro.core.precursor import DetectorConfig
 from repro.core.retry import chain_stats
 from repro.ops import SweepRunner, get_scenario
 from repro.ops.sweep import compute_findings
+from repro.telemetry.exporters import ExporterSuite, NodeStateBatch
 
 
 def assert_result_parity(ref, got, tag=""):
@@ -164,6 +168,112 @@ def test_drain_parity():
         assert_result_parity(ref, batched[i], f"drain-seed{seed}")
         n_drains += ref.control.n_drains
     assert n_drains > 0, "window executed no drains — parity untested"
+
+
+# ---------------------------------------------------------------------------
+# concurrent telemetry rounds: each seed's chunks stay its own
+# ---------------------------------------------------------------------------
+
+def _concurrent_case(case):
+    if case == "proactive":
+        cfg = get_scenario("proactive").replace(
+            duration_days=2.0, telemetry_pad_metrics=16).to_campaign_config(0)
+        return cfg, [0, 1, 2, 3]
+    # seed 35 drains at 45.5 h, in a round shared with another seed, and
+    # the rest of that `_emit` call goes on without it
+    cfg = CampaignConfig(duration_h=2 * 24.0, telemetry_pad_metrics=0,
+                         telemetry_store=False,
+                         control=ControlConfig(drain=True))
+    return cfg, [35, 0, 1, 2]
+
+
+@pytest.mark.parametrize("case", ["proactive", "drain"])
+def test_concurrent_rounds_match_single_seed_runs(case):
+    """Four seeds whose chunks are generated together on the pool give
+    the alarms, results and findings of four single-seed runs of the
+    same engine, whose chunks all run inline."""
+    cfg, seeds = _concurrent_case(case)
+    tracing.enable()
+    tracing.reset()
+    try:
+        batched = BatchedCampaignEngine(cfg).run(seeds)
+        counters = tracing.snapshot()["counters"]
+    finally:
+        tracing.disable()
+        tracing.reset()
+    # some round generated several chunks at once
+    assert counters["engine.telemetry_path_ticks"] \
+        < counters["engine.telemetry_ticks"]
+    findings = BatchedCampaignEngine(cfg).run_findings(seeds)
+    for i, seed in enumerate(seeds):
+        alone = BatchedCampaignEngine(cfg).run([seed])[0]
+        assert alone.control.alarms == batched[i].control.alarms, seed
+        assert_result_parity(alone, batched[i], f"{case}-seed{seed}")
+        assert findings[i] == \
+            BatchedCampaignEngine(cfg).run_findings([seed])[0], seed
+    if case == "drain":
+        assert sum(r.control.n_drains for r in batched) > 0
+    else:
+        assert sum(len(r.control.alarms) for r in batched) > 0
+
+
+def _exporter_rounds(seed):
+    """Two chunks of one exporter's telemetry, with precursor and XID
+    rows, so every draw family and the remap counters are exercised."""
+    n = 16
+    exp = ExporterSuite(n, seed=seed, n_pad=32)
+    exp.begin_gradual_precursor(3, 0.1, until_h=1.5)
+    jobs = []
+    for k0, k1 in ((0, 150), (150, 300 + 10 * seed)):
+        ts = np.arange(k0, k1) * TICK_H
+        training = np.ones(n)
+        training[5] = 0.0
+        batch = NodeStateBatch.constant(len(ts), n, training=training)
+        rows = [(7, FailureEvent(time_h=float(ts[7]), node=3, kind="xid",
+                                 xid=94)),
+                (20, FailureEvent(time_h=float(ts[20]), node=9, kind="xid",
+                                  xid=79))]
+        jobs.append((ts, batch, rows))
+    return exp, jobs
+
+
+def test_pool_chunks_bit_equal_to_serial_calls():
+    """Four `ExporterSuite`s on the pool, round after round, give arrays
+    bit-equal to serial calls and leave the same remap counters."""
+    serial, pooled = [], []
+    for seed in range(4):
+        exp, jobs = _exporter_rounds(seed)
+        serial.append([exp.tick_batch(*job) for job in jobs])
+        serial[-1].append((exp.remap_corr, exp.remap_uncorr))
+    fresh = [_exporter_rounds(seed) for seed in range(4)]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for r in range(2):
+            pooled.append(batch_mod._tick_chunks(
+                [(exp, *jobs[r]) for exp, jobs in fresh]))
+    finally:
+        sys.setswitchinterval(switch)
+    for i, (exp, _) in enumerate(fresh):
+        for r in range(2):
+            want, got = serial[i][r], pooled[r][i]
+            assert want.keys() == got.keys()
+            for key in want:
+                assert want[key].dtype == got[key].dtype, key
+                assert np.array_equal(want[key], got[key]), (i, r, key)
+        assert np.array_equal(exp.remap_corr, serial[i][2][0]), i
+        assert np.array_equal(exp.remap_uncorr, serial[i][2][1]), i
+        assert exp.remap_corr.sum() > 0 and exp.remap_uncorr.sum() > 0
+
+
+def test_pool_chunk_error_reaches_the_caller():
+    class Broken:
+        def tick_batch(self, ts, batch, rows):
+            raise RuntimeError("exporter failed")
+
+    exp, jobs = _exporter_rounds(0)
+    with pytest.raises(RuntimeError, match="exporter failed"):
+        batch_mod._tick_chunks([(exp, *jobs[0]), (Broken(), *jobs[0])])
 
 
 def test_infra_band_parity_8_seeds():

@@ -184,11 +184,28 @@ def test_proactive_findings_identical_and_spans_named():
         assert snap["spans"][name]["calls"] == 1
     c = snap["counters"]
     assert 0 < c["detector.compiled_seed_ticks"] <= c["detector.seed_ticks"]
-    # one simulated day of 30 s scrapes, each tick through pass 1 once
-    assert c["detector.seed_ticks"] == 2880
+    # one simulated day of 30 s scrapes, each tick generated and through
+    # pass 1 once; a lone seed's rounds are all its own ticks
+    assert c["detector.seed_ticks"] == c["engine.telemetry_ticks"] \
+        == c["engine.telemetry_path_ticks"] == 2880
     events = snap["spans"]["engine.events"]
     inner = sum(snap["spans"][k]["total_s"] for k in (
         "engine.telemetry", "detector.pass1", "detector.attribute",
         "control.apply"))
     assert events["self_s"] == pytest.approx(events["total_s"] - inner,
                                              abs=1e-6)
+
+
+def test_telemetry_path_ticks_bound_concurrent_rounds():
+    """With several seeds every generated seed-tick still reaches pass 1
+    once, and the longest chunk of each round sums to fewer ticks."""
+    cfg = get_scenario("proactive").replace(
+        duration_days=1.0, detector_backend="xla",
+        telemetry_pad_metrics=0).to_campaign_config(0)
+    tracing.enable()
+    run_findings_stacked([cfg], [0, 1, 2, 3])
+    c = tracing.snapshot()["counters"]
+    assert c["engine.telemetry_ticks"] == c["detector.seed_ticks"] \
+        == 4 * 2880
+    assert 2880 <= c["engine.telemetry_path_ticks"] \
+        < c["engine.telemetry_ticks"]
