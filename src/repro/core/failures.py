@@ -529,10 +529,16 @@ def degraded_overlap_h(windows, t0: float, t1: float, nodes) -> float:
     """Effective training hours lost to degradation windows overlapping a
     session's [t0, t1) run span on its gang nodes: overlap * (1 - 1/sev)
     at plateau severity (the ramp is a telemetry shape, not an accounting
-    term — keeping the ledger a closed form both engines share)."""
+    term — keeping the ledger a closed form both engines share).
+
+    ``nodes`` is the gang: a collection of node ids, or a boolean row
+    over the pool's nodes (one lookup a window, whatever the gang's
+    size)."""
+    in_gang = nodes.__getitem__ if isinstance(nodes, np.ndarray) \
+        and nodes.dtype == bool else nodes.__contains__
     total = 0.0
     for node, w0, w1, sev, _kind, _onset in windows:
-        if node in nodes:
+        if in_gang(node):
             ov = min(t1, w1) - max(t0, w0)
             if ov > 0.0:
                 total += ov * (1.0 - 1.0 / sev)

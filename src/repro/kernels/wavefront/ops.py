@@ -10,7 +10,8 @@ identical** to ``BatchedCampaignEngine.run_findings`` / the scalar
    and retry-delay tables become padded per-lane arrays;
 2. **device pass** (``ref.py``) — one jitted ``lax.while_loop`` advances
    all lanes event by event, emitting a per-iteration record stream,
-   integer accumulators and per-session gang bitmasks;
+   integer accumulators and, only where some lane has degradation
+   windows, per-session gang bitmasks (packed);
 3. **host replay** (here) — the float accounting folds (checkpoint
    catch-up, lost work, run-hours, downtime windows, retry-gap lists,
    degradation overlaps) rerun in numpy along the iteration axis, where
@@ -28,9 +29,16 @@ beats a speculative one (same precedent as the detector's numpy floor).
 which the device round trip costs more than the numpy pass.
 
 Cap discipline: device arrays are fixed-size (tape lengths, session
-slots, iteration budget).  The core flags any lane that approaches a
-cap; the driver doubles the flagged capacities and reruns — results are
-only ever read from a clean pass.
+slots, iteration budget), sized from the block's largest failure count
+(``WavefrontCaps.sized``).  The core flags any lane that approaches a
+cap; the driver doubles the capacities and reruns — results are only
+ever read from a clean pass.
+
+Tracing counters (``repro.tracing``): ``grid.cap_reruns`` (device passes
+rerun), ``grid.gang_mask_bytes`` (session gang masks fetched from the
+device, every pass) and ``grid.iterations`` (the clean pass's iteration
+count).  ``last_grid_pass`` keeps the last grid call's numbers whether
+or not tracing is on.
 """
 from __future__ import annotations
 
@@ -48,17 +56,23 @@ from repro.kernels.wavefront.ref import (F_ADVANCE, F_ALLOCFAIL,
                                          F_CHAIN_CLOSE, F_FINALIZE,
                                          F_LOST, F_PREP_OK, F_RUNNING,
                                          F_SESS_FAIL, F_START, F_VALID,
-                                         wavefront_core)
+                                         unpack_gang, wavefront_core)
 from repro.kernels.wavefront.tapes import (LaneTables, WavefrontCaps,
                                            build_lane_tables,
                                            concat_lane_tables,
-                                           pad_lanes_pow2)
+                                           max_failures, pad_lanes_pow2)
 
 __all__ = ["compiled_eligible", "resolve_wavefront_backend",
            "run_findings_compiled", "run_findings_grid",
            "fabric_query_batch"]
 
 _MAX_CAP_RETRIES = 6
+
+# The last run_findings_grid call's device-pass numbers, as the tracing
+# counters of one call give them: ``cap_reruns``, ``gang_mask_bytes``
+# and ``iterations``.  Kept whether or not tracing is on (one dict a
+# call), for a caller that cannot open a tracing window around the call.
+last_grid_pass: Dict[str, int] = {}
 
 
 def compiled_eligible(cfg: CampaignConfig) -> bool:
@@ -106,8 +120,11 @@ def device_tables(tables: LaneTables) -> Dict[str, np.ndarray]:
 
 
 def _run_core(tables: LaneTables, backend: str, interpret: bool):
+    """One device pass.  Session gang masks are carried only where some
+    lane has degradation windows, their one reader (``_degraded``)."""
     import jax
     import jax.numpy as jnp
+    masks = any(tables.deg_windows)
     with jax.enable_x64(True):
         with tracing.span("grid.upload"):
             P = {k: jnp.asarray(v)
@@ -115,7 +132,7 @@ def _run_core(tables: LaneTables, backend: str, interpret: bool):
         with tracing.span("grid.run"):          # dispatch through fetch
             out = wavefront_core(
                 P, n_nodes=tables.n_nodes,
-                n_sessions=tables.caps.n_sessions,
+                n_sessions=tables.caps.n_sessions if masks else 0,
                 n_iters=tables.caps.n_iters,
                 backend=backend, interpret=interpret)
             host = {k: np.asarray(v) for k, v in out.items()}
@@ -123,17 +140,25 @@ def _run_core(tables: LaneTables, backend: str, interpret: bool):
     return host
 
 
-def _run_with_caps(build, backend: str, interpret: bool):
+def _run_with_caps(build, caps: WavefrontCaps, backend: str,
+                   interpret: bool):
     """build(caps) -> LaneTables; rerun with doubled caps until no lane
     overflows (results are never read from an overflowed pass)."""
-    caps = None
+    fetched = 0
     for attempt in range(_MAX_CAP_RETRIES):
         if attempt:
             tracing.count("grid.cap_reruns")
         tables = build(caps)
-        caps = tables.caps
         host = _run_core(tables, backend, interpret)
+        mask_bytes = host["se_gang"].nbytes if "se_gang" in host else 0
+        tracing.count("grid.gang_mask_bytes", mask_bytes)
+        fetched += mask_bytes
         if not host["overflow"][tables.device["lane_on"]].any():
+            tracing.count("grid.iterations", int(host["it"]))
+            last_grid_pass.clear()
+            last_grid_pass.update(cap_reruns=attempt,
+                                  gang_mask_bytes=fetched,
+                                  iterations=int(host["it"]))
             return tables, host
         caps = caps.doubled(("n_uniform", "n_manual", "n_struct",
                              "n_sessions", "n_iters"))
@@ -143,6 +168,35 @@ def _run_with_caps(build, backend: str, interpret: bool):
 
 
 # -- host replay of the float accounting folds -------------------------------
+
+class _Lists:
+    """Per-lane lists of values (one or more columns) appended in replay
+    order: kept as (lanes, values) chunks, one per replay step, and
+    grouped by lane at the first read (a stable sort keeps each lane's
+    order)."""
+
+    def __init__(self, L: int, n_cols: int):
+        self.L = L
+        self._lanes: List[np.ndarray] = []
+        self._chunks: List[List[np.ndarray]] = [[] for _ in range(n_cols)]
+        self._cols: Optional[List[np.ndarray]] = None
+
+    def add(self, lanes: np.ndarray, *cols: np.ndarray) -> None:
+        self._lanes.append(lanes)
+        for chunks, col in zip(self._chunks, cols):
+            chunks.append(col)
+
+    def lane(self, s: int, col: int = 0) -> np.ndarray:
+        if self._cols is None:
+            lanes = np.concatenate(self._lanes) if self._lanes \
+                else np.zeros(0, dtype=np.int64)
+            order = np.argsort(lanes, kind="stable")
+            self._cols = [np.concatenate(chunks)[order] if chunks
+                          else np.zeros(0) for chunks in self._chunks]
+            self._bounds = np.searchsorted(lanes[order],
+                                           np.arange(self.L + 1))
+        return self._cols[col][self._bounds[s]:self._bounds[s + 1]]
+
 
 class _Replay:
     """Per-lane accounting state driven by the device record stream."""
@@ -161,10 +215,10 @@ class _Replay:
         self.retry_reached = np.zeros(L, dtype=bool)
         self.run_sum = np.zeros(L)
         self.f4 = np.zeros((L, 3), dtype=np.int64)
-        self.gaps: List[List[float]] = [[] for _ in range(L)]
-        self.lost: List[List[float]] = [[] for _ in range(L)]
-        self.downtimes: List[List[tuple]] = [[] for _ in range(L)]
-        self.sess: List[List[tuple]] = [[] for _ in range(L)]
+        self.gaps = _Lists(L, 1)         # retry gap, minutes
+        self.lost = _Lists(L, 1)         # lost work, hours
+        self.downtimes = _Lists(L, 2)    # hours, automatic
+        self.sess = _Lists(L, 2)         # session start, end
 
 
 def _replay(tables: LaneTables, host: Dict[str, np.ndarray]) -> _Replay:
@@ -178,62 +232,76 @@ def _replay(tables: LaneTables, host: Dict[str, np.ndarray]) -> _Replay:
     interval = tables.interval
     duration = tables.duration
     it_count = int(host["it"])
-    rec_t, rec_fl = host["rec_t"], host["rec_flags"]
+    rec_t, rec_fl = host["rec_t"], host["rec_flags"][:it_count]
     isnan = np.isnan
+
+    def flag(f):
+        """(iterations, L) mask of flag ``f``, and its rows' any."""
+        m = (rec_fl & f) != 0
+        return m, m.any(axis=1)
+
+    M_START, _ = flag(F_START)
+    M_AF, _ = flag(F_ALLOCFAIL)
+    M_ATT = M_START | M_AF
+    ANY_ATT = M_ATT.any(axis=1)
+    M_POK, ANY_POK = flag(F_PREP_OK)
+    M_FAIL, ANY_FAIL = flag(F_SESS_FAIL)
+    M_LOST, ANY_LOST = flag(F_LOST)
+    M_CC, ANY_CC = flag(F_CHAIN_CLOSE)
+    M_FIN, ANY_FIN = flag(F_FINALIZE)
+    M_ADV, _ = flag(F_ADVANCE)
+    M_RUN = M_ADV & ((rec_fl & F_RUNNING) != 0)
+    ANY_RUN = M_RUN.any(axis=1)
+    ACTIVE = rec_fl.any(axis=1)
     for it in range(it_count):
-        fl = rec_fl[it]
-        if not fl.any():
+        if not ACTIVE[it]:
             continue
         tn = rec_t[it]
         t = R.cur_t
 
-        m_start = (fl & F_START) != 0
-        m_af = (fl & F_ALLOCFAIL) != 0
-        m_att = m_start | m_af
-        if m_att.any():
+        m_start, m_af, m_att = M_START[it], M_AF[it], M_ATT[it]
+        if ANY_ATT[it]:
             gm = m_att & ~isnan(R.prev_end)
             if gm.any():
-                gv = (t - R.prev_end) * 60.0
-                for s in np.nonzero(gm)[0]:
-                    R.gaps[s].append(float(gv[s]))
+                idx = np.nonzero(gm)[0]
+                R.gaps.add(idx, (t[idx] - R.prev_end[idx]) * 60.0)
             R.n_att[m_att] += 1
             R.prev_end[m_af] = t[m_af]
             R.prev_end[m_start] = np.nan
             R.started[m_start] = np.nan
             R.open_sess[m_start] = True
 
-        m_pok = (fl & F_PREP_OK) != 0
-        if m_pok.any():
+        m_pok = M_POK[it]
+        if ANY_POK[it]:
             R.started[m_pok] = t[m_pok]
             R.retry_reached[m_pok & (R.n_att != 1)] = True
             R.last_ckpt[m_pok] = t[m_pok]
             R.last_save[m_pok] = t[m_pok]
             dc = m_pok & ~isnan(R.down_since)
-            for s in np.nonzero(dc)[0]:
-                R.downtimes[s].append(
-                    (float(t[s] - R.down_since[s]), bool(R.down_auto[s])))
+            idx = np.nonzero(dc)[0]
+            R.downtimes.add(idx, t[idx] - R.down_since[idx],
+                            R.down_auto[idx])
             R.down_since[dc] = np.nan
             R.down_auto[dc] = True
 
-        m_fail = (fl & F_SESS_FAIL) != 0
-        m_lost = (fl & F_LOST) != 0
-        if m_fail.any():
-            if m_lost.any():            # lost precedes the teardown fold
-                lv = np.minimum(t - R.last_save, interval)
-                for s in np.nonzero(m_lost)[0]:
-                    R.lost[s].append(float(lv[s]))
+        m_fail, m_lost = M_FAIL[it], M_LOST[it]
+        if ANY_FAIL[it]:
+            if ANY_LOST[it]:            # lost precedes the teardown fold
+                idx = np.nonzero(m_lost)[0]
+                R.lost.add(idx, np.minimum(t[idx] - R.last_save[idx],
+                                           interval[idx]))
             rs = m_fail & ~isnan(R.started)
             R.run_sum[rs] += np.maximum(0.0, t[rs] - R.started[rs])
-            for s in np.nonzero(m_fail)[0]:
-                R.sess[s].append((float(R.started[s]), float(t[s])))
+            idx = np.nonzero(m_fail)[0]
+            R.sess.add(idx, R.started[idx], t[idx])
             R.started[m_fail] = np.nan
             R.open_sess[m_fail] = False
             R.prev_end[m_fail] = t[m_fail]
             dn = m_fail & isnan(R.down_since)
             R.down_since[dn] = t[dn]
 
-        m_cc = (fl & F_CHAIN_CLOSE) != 0
-        if m_cc.any():
+        m_cc = M_CC[it]
+        if ANY_CC[it]:
             g = m_cc & (R.n_att > 1)
             R.f4[g, 0] += 1
             R.f4[g, 1] += R.n_att[g]
@@ -243,13 +311,13 @@ def _replay(tables: LaneTables, host: Dict[str, np.ndarray]) -> _Replay:
             R.prev_end[m_cc] = np.nan
             R.down_auto[m_cc] = False
 
-        m_fin = (fl & F_FINALIZE) != 0
-        if m_fin.any():
+        m_fin = M_FIN[it]
+        if ANY_FIN[it]:
             fo = m_fin & R.open_sess
             rs = fo & ~isnan(R.started)
             R.run_sum[rs] += np.maximum(0.0, duration[rs] - R.started[rs])
-            for s in np.nonzero(fo)[0]:
-                R.sess[s].append((float(R.started[s]), float(duration[s])))
+            idx = np.nonzero(fo)[0]
+            R.sess.add(idx, R.started[idx], duration[idx])
             R.open_sess[fo] = False
             R.started[fo] = np.nan
             g = m_fin & (R.n_att > 1)
@@ -259,8 +327,8 @@ def _replay(tables: LaneTables, host: Dict[str, np.ndarray]) -> _Replay:
             R.n_att[m_fin] = 0
             R.retry_reached[m_fin] = False
 
-        m_run = ((fl & F_ADVANCE) != 0) & ((fl & F_RUNNING) != 0)
-        if m_run.any():
+        m_run = M_RUN[it]
+        if ANY_RUN[it]:
             k = np.floor((tn - R.last_ckpt + 1e-12)
                          / interval).astype(np.int64)
             k = np.where(m_run, np.maximum(k, 0), 0)
@@ -268,43 +336,51 @@ def _replay(tables: LaneTables, host: Dict[str, np.ndarray]) -> _Replay:
             R.last_ckpt += k * interval
             np.maximum(R.last_save, R.last_ckpt, out=R.last_save)
 
-        m_adv = (fl & F_ADVANCE) != 0
-        R.cur_t = np.where(m_adv, tn, R.cur_t)
+        R.cur_t = np.where(M_ADV[it], tn, R.cur_t)
     return R
 
 
 def _degraded(tables: LaneTables, host, R: _Replay,
               lane: int) -> List[float]:
+    """Per session of ``lane`` that reached RUNNING, the hours its gang
+    lost to degradation windows; only these rows of the packed session
+    gang masks are unpacked."""
     windows = tables.deg_windows[lane]
     if not windows:
         return []
     gang = host["se_gang"][lane]
     out: List[float] = []
-    for k, (t0, t1) in enumerate(R.sess[lane]):
+    for k, (t0, t1) in enumerate(zip(R.sess.lane(lane, 0).tolist(),
+                                     R.sess.lane(lane, 1).tolist())):
         if t0 != t0:                    # never reached RUNNING
             continue
-        nodes = np.nonzero(gang[k])[0].tolist()
-        d = degraded_overlap_h(windows, t0, t1, nodes)
+        d = degraded_overlap_h(windows, t0, t1,
+                               unpack_gang(gang[k], tables.n_nodes))
         if d:
             out.append(d)
     return out
+
+
+def _median(values: np.ndarray) -> Optional[float]:
+    return float(np.median(values)) if len(values) else None
 
 
 def _lane_findings(tables: LaneTables, host, R: _Replay,
                    lane: int) -> dict:
     duration = float(tables.duration[lane])
     n_chains, n_attempts, succ = (int(v) for v in R.f4[lane])
-    gaps = R.gaps[lane]
+    gaps = R.gaps.lane(lane)
     counts = host["npart_counts"][lane].astype(float)
     total = counts.sum()
     top3 = float(np.sort(counts)[::-1][:3].sum() / total) \
         if total else 0.0
     delib_frac = float(int(host["n_delib"][lane])
                        / max(int(host["n_intervals"][lane]), 1))
-    autos = [h for h, auto in R.downtimes[lane] if auto]
-    mans = [h for h, auto in R.downtimes[lane] if not auto]
+    down_h = R.downtimes.lane(lane, 0)
+    auto = R.downtimes.lane(lane, 1).astype(bool)
+    autos, mans = down_h[auto], down_h[~auto]
     run = float(R.run_sum[lane]) if tables.job_gt1[lane] else 0.0
-    lost = R.lost[lane]
+    lost = R.lost.lane(lane)
     ckpt_h = int(R.ckpt_events[lane]) \
         * float(tables.save_s[lane]) / 3600.0
     degraded = _degraded(tables, host, R, lane)
@@ -316,15 +392,15 @@ def _lane_findings(tables: LaneTables, host, R: _Replay,
         "n_failures": float(tables.n_failures[lane]),
         "n_sessions": float(host["n_sessions"][lane]),
         "ckpt_events": float(R.ckpt_events[lane]),
-        "mean_lost_h": float(np.mean(lost)) if lost else 0.0,
+        "mean_lost_h": float(np.mean(lost)) if len(lost) else 0.0,
         "f3_top3_share": top3,
         "f3_deliberate_fraction": delib_frac,
         "f4_n_chains": float(n_chains),
         "f4_n_attempts": float(n_attempts),
         "f4_success_rate": succ / n_chains if n_chains else 0.0,
-        "f4_gap_median_min": float(np.median(gaps)) if gaps else None,
-        "f4_auto_downtime_h": float(np.median(autos)) if autos else None,
-        "f4_manual_downtime_h": float(np.median(mans)) if mans else None,
+        "f4_gap_median_min": _median(gaps),
+        "f4_auto_downtime_h": _median(autos),
+        "f4_manual_downtime_h": _median(mans),
         "infra_n_events": float(tables.infra_n[lane]),
         "infra_degraded_h": deg_h,
         # eligibility excludes the correlated band, so these lanes carry
@@ -349,6 +425,7 @@ def run_findings_grid(configs: Sequence[CampaignConfig],
     if interpret is None:
         interpret = not on_tpu()
     resolved = []
+    drawn = []                  # (injector, duration, schedules) drawn
     with tracing.span("grid.draws"):
         for cfg in configs:
             base = ClusterSim(cfg)
@@ -363,7 +440,14 @@ def run_findings_grid(configs: Sequence[CampaignConfig],
                 hot_fraction=rcfg.hot_fraction, hot_weight=rcfg.hot_weight,
                 kind_weights=rcfg.kind_weights,
                 topology_fanout=rcfg.topology_fanout, seed=rcfg.seed)
-            fails = injector.sample_batch(rcfg.duration_h, seeds)
+            # variants that differ only in policy share one failure
+            # process, and so one draw of its schedules
+            fails = next((f for inj, d, f in drawn
+                          if inj == injector and d == rcfg.duration_h),
+                         None)
+            if fails is None:
+                fails = injector.sample_batch(rcfg.duration_h, seeds)
+                drawn.append((injector, rcfg.duration_h, fails))
             resolved.append((rcfg, fails))
 
     def build(caps_in):
@@ -372,9 +456,10 @@ def run_findings_grid(configs: Sequence[CampaignConfig],
                       for rcfg, fails in resolved]
             return pad_lanes_pow2(concat_lane_tables(blocks))
 
-    first = build(caps)
-    tables, host = _run_with_caps(
-        lambda c: first if c is None else build(c), backend, interpret)
+    if caps is None:
+        caps = WavefrontCaps.sized(
+            max(max_failures(fails) for _, fails in resolved))
+    tables, host = _run_with_caps(build, caps, backend, interpret)
     with tracing.span("grid.replay"):
         R = _replay(tables, host)
     S = len(seeds)

@@ -25,9 +25,12 @@ exact round-half-even integer routine (`f64_add`).  All multiply-adds
 live in the host tapes/tables.  Float accounting folds (checkpoint
 catch-up, lost work, run-hours, downtime) do not happen here at all: the
 device emits a per-iteration record stream — ``(rec_t, rec_flags)`` with
-the event bits below — plus integer accumulators and per-session gang
-bitmasks, and the host *replay* (``ops.py``) reruns the folds in numpy,
-where double arithmetic matches the scalar engine exactly.
+the event bits below — plus integer accumulators, and the host *replay*
+(``ops.py``) reruns the folds in numpy, where double arithmetic matches
+the scalar engine exactly.  Where some lane of the block has
+degradation windows, the device also records each session's gang, bit
+packed (``pack_gang``), for the replay's degraded-hours ledger; a block
+with none carries no per-session state at all.
 
 The checkpoint catch-up in particular cannot be split across device
 iterations (``c + k1*i`` then ``+ k2*i`` differs bitwise from
@@ -43,7 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-__all__ = ["wavefront_core", "f64_add", "f64_night", "F_VALID",
+__all__ = ["wavefront_core", "f64_add", "f64_night", "pack_gang",
+           "unpack_gang", "F_VALID",
            "F_ADVANCE", "F_RUNNING", "F_START", "F_ALLOCFAIL", "F_PREP_OK",
            "F_SESS_FAIL", "F_LOST", "F_CHAIN_CLOSE", "F_FINALIZE"]
 
@@ -121,6 +125,24 @@ def f64_night(t):
     ip = jnp.minimum(ip, _ORD_MAX).astype(jnp.int32)
     hour, day = ip % 24, (ip // 24) % 7
     return (day >= 5) | (hour < 8) | (hour > 20) | ((hour == 20) & frac)
+
+
+def pack_gang(m):
+    """``(L, n)`` bool -> ``(L, ceil(n / 32))`` uint32: node ``j`` is bit
+    ``j % 32`` of word ``j // 32``."""
+    L, n = m.shape
+    W = -(-n // 32)
+    bits = jnp.pad(m, ((0, 0), (0, 32 * W - n))).reshape(L, W, 32)
+    shifts = jnp.arange(32, dtype=jnp.uint32)
+    return jnp.sum(bits.astype(jnp.uint32) << shifts, axis=2,
+                   dtype=jnp.uint32)
+
+
+def unpack_gang(words: np.ndarray, n: int) -> np.ndarray:
+    """One packed gang row (``(ceil(n / 32),)`` uint32, from
+    :func:`pack_gang`) as an ``(n,)`` bool row, on the host."""
+    b = np.ascontiguousarray(words, dtype="<u4").view(np.uint8)
+    return np.unpackbits(b, bitorder="little")[:n].astype(bool)
 
 
 def _row(tab, ptr):
@@ -211,7 +233,6 @@ def _iteration(st, P, backend: str, interpret: bool):
     alive = st["alive"]
     L, n = st["healthy"].shape
     iota_n = lax.broadcasted_iota(jnp.int32, (L, n), 1)
-    rows = jnp.arange(L)
     zero_b = jnp.zeros(L, dtype=bool)
     nan_v = jnp.full(L, _NAN, dtype=t.dtype)
     flags = jnp.zeros(L, dtype=jnp.int32)
@@ -255,14 +276,14 @@ def _iteration(st, P, backend: str, interpret: bool):
     flags = flags | jnp.where(afail, F_ALLOCFAIL, 0)
     st, flags = _sched_next(st, flags, P, afail, t, nan_v, zero_b, True)
 
-    # gang-feasible: open the session, record the gang bitmask
+    # gang-feasible: open the session (and record its packed gang where
+    # the block carries session gang masks)
     st["in_gang"] = jnp.where(okm[:, None], chosen, st["in_gang"])
-    NS = st["se_gang"].shape[1]
-    sidx = jnp.clip(st["sess_ctr"], 0, NS - 1)
-    prev_gang = st["se_gang"][rows, sidx]
-    st["se_gang"] = st["se_gang"].at[rows, sidx].set(
-        jnp.where(okm[:, None], chosen, prev_gang))
-    st["sess_ctr"] = st["sess_ctr"] + okm
+    if "se_gang" in st:
+        rows = jnp.arange(L)
+        sidx = jnp.clip(st["n_sessions"], 0, st["se_gang"].shape[1] - 1)
+        st["se_gang"] = st["se_gang"].at[rows, sidx].set(jnp.where(
+            okm[:, None], pack_gang(chosen), st["se_gang"][rows, sidx]))
     st["n_sessions"] = st["n_sessions"] + okm.astype(jnp.int32)
     flags = flags | jnp.where(okm, F_START, 0)
     # transient-retry roll + pre-transformed load-duration draw
@@ -399,9 +420,11 @@ def _iteration(st, P, backend: str, interpret: bool):
     # cap sentries: a lane within one iteration's worth of consumption of
     # any cap is flagged and halted before a clipped read can corrupt it
     U, M, X = P["u"].shape[1], P["man_day"].shape[1], P["x_half"].shape[1]
-    NS = st["se_gang"].shape[1]
     lane_over = (st["u_ptr"] > U - 8) | (st["m_ptr"] > M - 4) \
-        | (st["x_ptr"] > X - 4) | (st["sess_ctr"] > NS - 2)
+        | (st["x_ptr"] > X - 4)
+    if "se_gang" in st:
+        lane_over = lane_over \
+            | (st["n_sessions"] > st["se_gang"].shape[1] - 2)
     st["overflow"] = st["overflow"] | (st["alive"] & lane_over)
     st["alive"] = st["alive"] & ~lane_over
     st["it"] = it + 1
@@ -415,9 +438,12 @@ def wavefront_core(P, *, n_nodes: int, n_sessions: int, n_iters: int,
     """Run the compiled wavefront over the lane tables ``P`` (the
     ``LaneTables.device`` dict as jnp arrays, every float table as the
     int64 bit patterns of its doubles).  Returns the record stream
-    (``rec_t`` as bit patterns too), session gang bitmasks, integer
-    accumulators, overflow flags and the iteration count — everything
-    the host replay needs."""
+    (``rec_t`` as bit patterns too), integer accumulators, overflow flags
+    and the iteration count — everything the host replay needs — and,
+    where ``n_sessions`` > 0, the first ``n_sessions`` sessions' gangs of
+    every lane as ``se_gang`` (``(L, n_sessions, ceil(n_nodes / 32))``
+    uint32, :func:`pack_gang`).  ``n_sessions`` 0 carries none: a block
+    with no degradation window has no reader for them."""
     L = P["u"].shape[0]
     n, NS, I = n_nodes, n_sessions, n_iters
     f64 = P["u"].dtype                 # int64 bit patterns
@@ -438,7 +464,6 @@ def wavefront_core(P, *, n_nodes: int, n_sessions: int, n_iters: int,
         "fail_ptr": jnp.zeros(L, dtype=jnp.int32),
         "esc_ptr": jnp.zeros(L, dtype=jnp.int32),
         "iso_ctr": jnp.zeros(L, dtype=jnp.int32),
-        "sess_ctr": jnp.zeros(L, dtype=jnp.int32),
         "healthy": jnp.ones((L, n), dtype=bool),
         "excl": jnp.zeros((L, n), dtype=bool),
         "in_gang": jnp.zeros((L, n), dtype=bool),
@@ -449,12 +474,13 @@ def wavefront_core(P, *, n_nodes: int, n_sessions: int, n_iters: int,
         "n_intervals": jnp.zeros(L, dtype=jnp.int32),
         "n_delib": jnp.zeros(L, dtype=jnp.int32),
         "n_sessions": jnp.zeros(L, dtype=jnp.int32),
-        "se_gang": jnp.zeros((L, NS, n), dtype=bool),
         "rec_t": jnp.zeros((I, L), f64),
         "rec_flags": jnp.zeros((I, L), dtype=jnp.int32),
         "overflow": jnp.zeros(L, dtype=bool),
         "it": jnp.int32(0),
     }
+    if NS:
+        st["se_gang"] = jnp.zeros((L, NS, -(-n // 32)), dtype=jnp.uint32)
 
     def cond(st):
         return jnp.any(st["alive"]) & (st["it"] < I)
@@ -467,4 +493,4 @@ def wavefront_core(P, *, n_nodes: int, n_sessions: int, n_iters: int,
     st["overflow"] = st["overflow"] | st["alive"]
     return {k: st[k] for k in (
         "rec_t", "rec_flags", "se_gang", "npart_counts", "n_intervals",
-        "n_delib", "n_sessions", "overflow", "it")}
+        "n_delib", "n_sessions", "overflow", "it") if k in st}

@@ -37,17 +37,29 @@ import numpy as np
 
 from repro.core.cluster import (RNG_STREAM_MANUAL, RNG_STREAM_STRUCT,
                                 CampaignConfig)
-from repro.core.failures import (FailureBatch, degradation_windows,
-                                 escalation_events)
+from repro.core.failures import (CORRELATED_KINDS, DEGRADE_KINDS,
+                                 KIND_NAMES, FailureBatch,
+                                 degradation_windows, escalation_events)
 from repro.core.retry import RetryEngine, RetryPolicy
 
 __all__ = ["WavefrontCaps", "LaneTables", "build_lane_tables",
-           "concat_lane_tables", "pad_lanes_pow2"]
+           "concat_lane_tables", "max_failures", "pad_lanes_pow2"]
 
 # load-duration uniform widths (bit-exact fast forms of the scalar
 # draws, shared with the numpy engines: uniform(a, b) == a + (b-a)*u)
 _W_LOAD = 0.3 - (-0.08)
 _W_FAIL = 0.15 - 0.05
+
+# Kind codes whose events open degradation windows or escalate: a lane
+# with none of them has neither, and its events are not materialized.
+_WINDOW_KINDS = np.array(sorted(KIND_NAMES.index(k)
+                                for k in DEGRADE_KINDS | CORRELATED_KINDS))
+
+
+# Cap consumption per failure of a block's busiest lane (see
+# ``WavefrontCaps.sized``).
+_PER_FAILURE = {"n_uniform": 12, "n_manual": 2, "n_struct": 2,
+                "n_sessions": 6, "n_iters": 12}
 
 
 @dataclass(frozen=True)
@@ -56,13 +68,53 @@ class WavefrontCaps:
 
     Each cap carries slack beyond the expected consumption; the device
     flags any lane that comes within one iteration's worth of a cap and
-    the driver re-runs with that cap doubled (see ``ops.py``).
+    the driver re-runs with that cap doubled (see ``ops.py``).  The
+    defaults are the floors of :meth:`sized`.
     """
     n_uniform: int = 2048        # main-stream uniforms per lane
     n_manual: int = 512          # manual-delay draws per lane
     n_struct: int = 512          # structural-fix draws per lane
-    n_sessions: int = 512        # session records per lane
+    n_sessions: int = 512        # session gang-mask slots per lane
     n_iters: int = 4096          # wavefront iterations
+
+    @classmethod
+    def sized(cls, max_failures: int) -> "WavefrontCaps":
+        """Caps for a block whose busiest lane draws ``max_failures``
+        failures: each cap is ``max(default, next_pow2(rate *
+        max_failures))`` with the per-failure rates of ``_PER_FAILURE``
+        (powers of two keep the jit keys few).
+
+        The rates come from what clean passes consumed (every cap raised,
+        on the CPU), per lane, largest over the block, divided by the
+        block's largest failure count:
+
+        * 2,176-node pool, 2,048-node gang, 54 d (``llama3-16k``; 4
+          variants x seeds 0..63, largest failure count 486): 4,200
+          iterations (8.6 a failure), 3,791 uniforms (7.8), 1,792
+          sessions (3.7), 203 manual and 236 structural draws (0.4, 0.5);
+          with automatic retry off, 493 and 351 (1.0, 0.7).  The rates
+          leave at least 1.4x of room above these.
+        * 63-node pool, 60-node gang, 73 d (``paper-63n``; 10 variants x
+          seeds 0..99, largest failure count 81): 1,349 iterations, 1,783
+          uniforms, 371 sessions, 455 manual and 196 structural draws.  A
+          small pool retries through long alloc-fail chains while its
+          three spares are in repair, so it consumes more per failure (up
+          to 31 uniforms, and 14 manual draws without automatic retry).
+          The floors hold such blocks: the rates pass them only beyond
+          85 (sessions) to 341 (iterations) failures a lane.
+
+        The retry policy and ``max_retries`` do not enter: each rate is
+        the largest over every retry policy measured (fixed, exponential
+        backoff, XID branch, structural stop, none), and the bound they
+        give, ``max_retries + 1`` sessions a failure, is eight times
+        looser than what lanes use.  The doubling rerun in
+        ``ops._run_with_caps`` stays the guard.
+        """
+        from repro.kernels.common import next_pow2
+        floor = cls()
+        return replace(floor, **{
+            k: max(getattr(floor, k), next_pow2(rate * int(max_failures)))
+            for k, rate in _PER_FAILURE.items()})
 
     def doubled(self, which: Sequence[str]) -> "WavefrontCaps":
         return replace(self, **{k: 2 * getattr(self, k) for k in which})
@@ -95,6 +147,11 @@ class LaneTables:
         return len(self.seeds)
 
 
+def max_failures(fails: FailureBatch) -> int:
+    """The largest failure count of one lane of ``fails``."""
+    return int(np.diff(fails.offsets).max(initial=0))
+
+
 def _delay_table(cfg: CampaignConfig, engine: RetryEngine,
                  n_rows: int) -> np.ndarray:
     """``dna[k]`` = automatic-retry delay (hours) after attempt count
@@ -120,8 +177,10 @@ def build_lane_tables(cfg: CampaignConfig, fails: FailureBatch,
                       caps: Optional[WavefrontCaps] = None) -> LaneTables:
     """Materialize one config's S seed lanes (config must be resolved —
     i.e. ``ClusterSim(cfg).cfg`` — so storage-derived checkpoint params
-    are final)."""
-    caps = caps if caps is not None else WavefrontCaps()
+    are final).  ``caps`` defaults to :meth:`WavefrontCaps.sized` for
+    these lanes."""
+    caps = caps if caps is not None \
+        else WavefrontCaps.sized(max_failures(fails))
     S, n = len(seeds), cfg.n_nodes
     U, M, X = caps.n_uniform, caps.n_manual, caps.n_struct
     engine = RetryEngine(cfg.retry)
@@ -183,9 +242,13 @@ def build_lane_tables(cfg: CampaignConfig, fails: FailureBatch,
                     d = engine.next_delay_min(1, xid=xid)
                     if d is not None:
                         fdelay[i, j] = d / 60.0
-        evs = fails.events(i)
-        deg_windows.append(degradation_windows(evs))
-        es = escalation_events(evs)
+        if np.isin(fails.kind[o0:o1], _WINDOW_KINDS).any():
+            evs = fails.events(i)
+            deg_windows.append(degradation_windows(evs))
+            es = escalation_events(evs)
+        else:
+            deg_windows.append([])
+            es = []
         esc_rows.append(es)
         E = max(E, len(es))
     et = np.full((S, E + 1), np.inf)      # same +inf sentinel discipline

@@ -86,6 +86,49 @@ def test_wavefront_core_compiles(backend, one_chip, quiet_cache,
     assert ("tpu_custom_call" in hlo) == (backend == "pallas")
 
 
+def test_wavefront_core_compiles_at_fleet_size(one_chip, quiet_cache):
+    """The device pass of ``mc_fleet.llama3-16k`` as the grid path runs
+    it: 4 variants x 64 lanes of a 2,176-node pool over 54 days, caps
+    sized from the lanes' failures, no session gang masks."""
+    import json
+    from pathlib import Path
+
+    from repro.core.cluster import ClusterSim
+    from repro.core.failures import FailureInjector
+    from repro.kernels.wavefront.ops import device_tables
+    from repro.kernels.wavefront.ref import wavefront_core
+    from repro.kernels.wavefront.tapes import (WavefrontCaps,
+                                               build_lane_tables,
+                                               concat_lane_tables,
+                                               max_failures,
+                                               pad_lanes_pow2)
+    from repro.ops.scenario import Scenario
+    conf = json.loads((Path(__file__).resolve().parents[1] / "bench"
+                       / "configs" / "llama3-16k.json").read_text())
+    seeds = list(range(64))
+    drawn = []
+    for spec in conf["variants"].values():
+        cfg = ClusterSim(Scenario.from_dict(spec).to_campaign_config(0)).cfg
+        inj = FailureInjector(
+            n_nodes=cfg.n_nodes, mtbf_h=cfg.mtbf_h,
+            hot_fraction=cfg.hot_fraction, hot_weight=cfg.hot_weight,
+            kind_weights=cfg.kind_weights,
+            topology_fanout=cfg.topology_fanout, seed=cfg.seed)
+        drawn.append((cfg, inj.sample_batch(cfg.duration_h, seeds)))
+    caps = WavefrontCaps.sized(max(max_failures(f) for _, f in drawn))
+    t = pad_lanes_pow2(concat_lane_tables(
+        [build_lane_tables(c, f, seeds, caps=caps) for c, f in drawn]))
+    assert (t.n_lanes, t.n_nodes) == (256, 2176) and not any(t.deg_windows)
+    with jax.enable_x64(True):
+        P = {k: _spec(v.shape, v.dtype, one_chip)
+             for k, v in device_tables(t).items()}
+        compiled = wavefront_core.lower(
+            P, n_nodes=t.n_nodes, n_sessions=0, n_iters=caps.n_iters,
+            backend="xla", interpret=False).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes < 2**30
+
+
 def test_robust_hit_blocks_compiles(one_chip, quiet_cache):
     from repro.kernels.robust_stats.kernel import robust_hit_blocks
     S, B, T, n = 16, 8, 256, 128
