@@ -145,10 +145,145 @@ def unpack_gang(words: np.ndarray, n: int) -> np.ndarray:
     return np.unpackbits(b, bitorder="little")[:n].astype(bool)
 
 
-def _row(tab, ptr):
-    """tab[(l, ptr[l])] with a clipped (overflow-safe) gather."""
-    idx = jnp.clip(ptr, 0, tab.shape[1] - 1)
-    return jnp.take_along_axis(tab, idx[:, None], axis=1)[:, 0]
+# -- tape reads: once an iteration, only what it can use -------------------
+#
+# On the chip a gather costs about the same for each element it fetches,
+# whatever the table, and the loop body is nearly all gathers; so each
+# iteration fetches, at the pointers' values on entry, just the elements
+# its steps can use, each once, and every read of a pointer picks from
+# them.  The four ``_sched_next`` calls of an iteration have masks that
+# exclude each other (alloc-fail, PREPARING failure, gang hit, escalation
+# crash: each of the later ones needs a session the earlier ones close or
+# never open), so in one iteration a lane moves
+#
+# * ``u_ptr`` by at most 5: an opened session's transient roll and load
+#   duration, then a gang hit's or an escalation crash's software roll
+#   and ``_sched_next``'s notice and misfix rolls.  An alloc-fail takes
+#   the readmit roll and ``_sched_next``'s two and opens nothing.  Rolls
+#   read offsets 0 to 4, the load duration 0 or 1;
+# * ``m_ptr`` by at most 1, read before it moves;
+# * ``x_ptr`` by at most 2 (a software follow-on, then the misfix
+#   horizon): ``x_full`` is read at offset 0, ``x_half`` at 0 or 1.
+#
+# ``READ`` holds these counts: every read of a pointer in an iteration
+# sits at an offset below its count.  The rolls need only ``u < p`` for
+# the lane's five probabilities (``_PROBS``), so the uniforms cross as
+# bits: entry ``p`` of the ``ubits`` table packs the comparisons of
+# positions ``p`` to ``p + READ["u_ptr"] - 1``, and one int32 a lane
+# serves every roll.  ``MARGIN`` holds the cap sentries' margins, at
+# least ``READ``: a lane is halted once its pointer passes its tape's
+# length less the margin, so an alive lane reads inside its tapes (reads
+# past a table's end clip, and only halted lanes make them).
+#
+# ``fail_ptr`` and ``esc_ptr`` move by at most 1.  The loop carries the
+# row each points at (``f_*``, ``e_*``), fetches the failure row after it
+# each iteration, and the escalation row only in an iteration where some
+# lane's escalation came due.
+READ = {"u_ptr": 5, "m_ptr": 1, "x_ptr": 2}
+MARGIN = {"u_ptr": 8, "m_ptr": 4, "x_ptr": 4}
+# the capped pointers and the tape whose length caps each
+CAPPED = {"u_ptr": "u", "m_ptr": "man_day", "x_ptr": "x_half"}
+_PROBS = ("p_readmit", "p_transient", "p_soft", "notice_p", "p_misfix")
+
+
+def _rows(*tabs):
+    """(L, N) tables -> one (k * N, L) table, lane-minor: position p of
+    the j-th table at row k * p + j."""
+    return jnp.stack([t.T for t in tabs], axis=1).reshape(
+        -1, tabs[0].shape[0])
+
+
+def _tape_tables(P):
+    """The tables the loop reads, built on the device once a pass, each
+    lane-minor (a gather that returns rows of lanes is the cheaper form
+    on the chip)."""
+    nb, w = len(_PROBS), READ["u_ptr"]
+    below = sum((P["u"] < P[p][:, None]).astype(jnp.int32) << k
+                for k, p in enumerate(_PROBS))
+    U = below.shape[1]
+    below = jnp.pad(below, ((0, 0), (0, w - 1)))
+    small = P["fnode"] | (P["fkcode"] << 16) \
+        | (P["fhw"].astype(jnp.int32) << 20) \
+        | (P["fhas_xid"].astype(jnp.int32) << 21)
+    return {
+        "ubits": _rows(sum(below[:, j:j + U] << (nb * j)
+                           for j in range(w))),
+        "dur": _rows(jnp.concatenate([P["dur_fail"], P["dur_warm"],
+                                      P["dur_cold"]], axis=1)),
+        "man": _rows(P["man_day"], P["man_night"]),
+        "x": _rows(P["x_half"], P["x_full"]),
+        "ftd": _rows(P["ft"], P["fdelay"]),
+        "fsmall": _rows(small),
+        "et": _rows(P["et"]), "enode": _rows(P["enode"]),
+    }
+
+
+def _take(tab, row, n=1):
+    """Rows ``row[l]`` to ``row[l] + n - 1`` of lane ``l``'s column of a
+    lane-minor table, as ``(n, L)``; rows clipped to the table."""
+    idx = row[None, :] + jnp.arange(n, dtype=row.dtype)[:, None]
+    return jnp.take_along_axis(tab, idx, axis=0, mode="clip")
+
+
+def _fail_row(T, ptr):
+    """The failure row at ``ptr``: its time, XID delay and packed small
+    fields."""
+    ftd = _take(T["ftd"], 2 * ptr, 2)
+    return {"f_t": ftd[0], "f_delay": ftd[1],
+            "f_small": _take(T["fsmall"], ptr)[0]}
+
+
+def _esc_row(T, ptr):
+    """The escalation row at ``ptr``: its crash time and node."""
+    return {"e_t": _take(T["et"], ptr)[0],
+            "e_node": _take(T["enode"], ptr)[0]}
+
+
+def _unpack_small(s):
+    """(node, kind code, hardware, has XID) of a packed failure row."""
+    return s & 0xFFFF, (s >> 16) & 0xF, (s >> 20) & 1 != 0, \
+        (s >> 21) & 1 != 0
+
+
+class _Reads:
+    """One iteration's tape reads, fetched at the pointers' values on
+    entry (see ``READ``)."""
+
+    def __init__(self, T, st, night):
+        self.T = T
+        self.p0 = {k: st[k] for k in READ}
+        self.ubits = _take(T["ubits"], st["u_ptr"])[0]
+        # this iteration's one possible manual draw, day or night as the
+        # clock gives it
+        self.man = _take(T["man"], 2 * st["m_ptr"] + night)[0]
+        self.x = _take(T["x"], 2 * st["x_ptr"], 2 * READ["x_ptr"] - 1)
+        # a software follow-on reads x_full before x_ptr moves
+        self.x_full = self.x[1]
+        self.next_fail = _fail_row(T, st["fail_ptr"] + 1)
+
+    def below(self, st, prob):
+        """``u < P[prob]`` for the uniform at the current ``u_ptr``."""
+        j = st["u_ptr"] - self.p0["u_ptr"]
+        return (self.ubits >> (len(_PROBS) * j + _PROBS.index(prob))) & 1 \
+            != 0
+
+    def dur(self, st, col):
+        """The load duration at the current ``u_ptr``: ``col`` 0 a failed
+        load, 1 warm, 2 cold."""
+        U = self.T["dur"].shape[0] // 3
+        return _take(self.T["dur"], col * U + st["u_ptr"])[0]
+
+    def x_half(self, st):
+        first = st["x_ptr"] == self.p0["x_ptr"]
+        return jnp.where(first, self.x[0], self.x[2])
+
+
+def _pick_col(tab, idx):
+    """tab[(l, idx[l])], the index clipped to the columns: a one-hot
+    select over the (few) columns."""
+    hot = lax.broadcasted_iota(jnp.int32, tab.shape, 1) \
+        == jnp.clip(idx, 0, tab.shape[1] - 1)[:, None]
+    return jnp.sum(jnp.where(hot, tab, 0), axis=1, dtype=tab.dtype)
 
 
 def _gang_select_xla(free, job):
@@ -185,7 +320,7 @@ def _fail_session(st, flags, P, mask, hw_new):
     return st, flags
 
 
-def _sched_next(st, flags, P, mask, t, evt_delay_h, evt_has_xid,
+def _sched_next(st, flags, P, rd, mask, t, evt_delay_h, evt_has_xid,
                 structural: bool):
     """Vector form of ``_schedule_next``: retry-vs-manual decision and
     the next pending-start time, with the exact scalar draw discipline
@@ -194,12 +329,11 @@ def _sched_next(st, flags, P, mask, t, evt_delay_h, evt_has_xid,
     once)."""
     n_att = st["n_att"]
     roll = mask & (n_att >= 3)
-    u_not = _row(P["u"], st["u_ptr"])
-    noticed = roll & (u_not < P["notice_p"])
+    noticed = roll & rd.below(st, "notice_p")
     st["u_ptr"] = st["u_ptr"] + roll
     if structural:
         noticed = noticed | (mask & P["struct_stop"])
-    dna_d = _row(P["dna"], n_att)
+    dna_d = _pick_col(P["dna"], n_att)
     delay = jnp.where(P["policy_xid"] & evt_has_xid, evt_delay_h, dna_d)
     retry = mask & P["retry_on"] & _finite(delay) \
         & (n_att < P["max_r"]) & ~noticed
@@ -209,15 +343,13 @@ def _sched_next(st, flags, P, mask, t, evt_delay_h, evt_has_xid,
     # manual-intervention branch: chain closes, operator responds with a
     # day/night exponential delay, and a misfixed root cause may extend
     # the structural-failure horizon
-    md = jnp.where(f64_night(t), _row(P["man_night"], st["m_ptr"]),
-                   _row(P["man_day"], st["m_ptr"]))
+    md = rd.man
     st["m_ptr"] = st["m_ptr"] + man
     pend_man = f64_add(t, md)
     st["pend"] = jnp.where(man, pend_man, st["pend"])
-    u_mis = _row(P["u"], st["u_ptr"])
-    mis = man & (u_mis < P["p_misfix"])
+    mis = man & rd.below(st, "p_misfix")
     st["u_ptr"] = st["u_ptr"] + man
-    xh = _row(P["x_half"], st["x_ptr"])
+    xh = rd.x_half(st)
     st["x_ptr"] = st["x_ptr"] + mis
     su = st["struct_until"]
     st["struct_until"] = jnp.where(
@@ -228,8 +360,9 @@ def _sched_next(st, flags, P, mask, t, evt_delay_h, evt_has_xid,
     return st, flags
 
 
-def _iteration(st, P, backend: str, interpret: bool):
+def _iteration(st, P, T, backend: str, interpret: bool):
     t = st["t"]
+    rd = _Reads(T, st, f64_night(t))
     alive = st["alive"]
     L, n = st["healthy"].shape
     iota_n = lax.broadcasted_iota(jnp.int32, (L, n), 1)
@@ -260,8 +393,7 @@ def _iteration(st, P, backend: str, interpret: bool):
     # candidates), then attempt bookkeeping and structural reschedule
     cand = (st["iso_reason"] > 0) & st["healthy"]
     has_cand = afail & jnp.any(cand, axis=1)
-    u_adm = _row(P["u"], st["u_ptr"])
-    readmit = has_cand & (u_adm < P["p_readmit"])
+    readmit = has_cand & rd.below(st, "p_readmit")
     st["u_ptr"] = st["u_ptr"] + has_cand
     ordm = jnp.where(cand, st["iso_order"], _ORD_MAX)
     rm_node = jnp.argmin(ordm, axis=1).astype(jnp.int32)
@@ -274,29 +406,26 @@ def _iteration(st, P, backend: str, interpret: bool):
 
     st["n_att"] = st["n_att"] + due_start.astype(jnp.int32)
     flags = flags | jnp.where(afail, F_ALLOCFAIL, 0)
-    st, flags = _sched_next(st, flags, P, afail, t, nan_v, zero_b, True)
+    st, flags = _sched_next(st, flags, P, rd, afail, t, nan_v, zero_b,
+                            True)
 
     # gang-feasible: open the session (and record its packed gang where
     # the block carries session gang masks)
     st["in_gang"] = jnp.where(okm[:, None], chosen, st["in_gang"])
     if "se_gang" in st:
-        rows = jnp.arange(L)
-        sidx = jnp.clip(st["n_sessions"], 0, st["se_gang"].shape[1] - 1)
-        st["se_gang"] = st["se_gang"].at[rows, sidx].set(jnp.where(
-            okm[:, None], pack_gang(chosen), st["se_gang"][rows, sidx]))
+        NS = st["se_gang"].shape[1]
+        sidx = jnp.where(okm, jnp.clip(st["n_sessions"], 0, NS - 1), NS)
+        st["se_gang"] = st["se_gang"].at[jnp.arange(L), sidx].set(
+            pack_gang(chosen), mode="drop")
     st["n_sessions"] = st["n_sessions"] + okm.astype(jnp.int32)
     flags = flags | jnp.where(okm, F_START, 0)
     # transient-retry roll + pre-transformed load-duration draw
     pf_pre = t < st["struct_until"]
     roll_tr = okm & ~pf_pre & ((st["n_att"] == 2) | (st["n_att"] == 3))
-    u_tr = _row(P["u"], st["u_ptr"])
-    trans = roll_tr & (u_tr < P["p_transient"])
+    trans = roll_tr & rd.below(st, "p_transient")
     st["u_ptr"] = st["u_ptr"] + roll_tr
     pf = pf_pre | trans
-    dur = jnp.where(pf, _row(P["dur_fail"], st["u_ptr"]),
-                    jnp.where(st["last_hw"],
-                              _row(P["dur_cold"], st["u_ptr"]),
-                              _row(P["dur_warm"], st["u_ptr"])))
+    dur = rd.dur(st, jnp.where(pf, 0, jnp.where(st["last_hw"], 2, 1)))
     st["u_ptr"] = st["u_ptr"] + okm
     st["prep_until"] = jnp.where(okm, f64_add(t, dur), st["prep_until"])
     st["prep_fails"] = jnp.where(okm, pf, st["prep_fails"])
@@ -313,16 +442,14 @@ def _iteration(st, P, backend: str, interpret: bool):
     st["cur_run"] = st["cur_run"] | pok
     flags = flags | jnp.where(pok, F_PREP_OK, 0)
     st, flags = _fail_session(st, flags, P, pfail, zero_b)
-    st, flags = _sched_next(st, flags, P, pfail, t, nan_v, zero_b, False)
+    st, flags = _sched_next(st, flags, P, rd, pfail, t, nan_v, zero_b,
+                            False)
 
     # 5. at most one failure event per lane per iteration
-    nf = _row(P["ft"], st["fail_ptr"])
+    nf = st["f_t"]
     fdue = alive & (nf <= t_eps)
-    fnode = _row(P["fnode"], st["fail_ptr"])
-    fk = _row(P["fkcode"], st["fail_ptr"])
-    fhw = _row(P["fhw"], st["fail_ptr"])
-    fdel = _row(P["fdelay"], st["fail_ptr"])
-    fhx = _row(P["fhas_xid"], st["fail_ptr"])
+    fnode, fk, fhw, fhx = _unpack_small(st["f_small"])
+    fdel = st["f_delay"]
     node_m = iota_n == fnode[:, None]
     # fail_slow: deliberate perf-degradation isolation (overwrite keeps
     # dict insertion order; a fresh key takes the next order counter)
@@ -347,46 +474,46 @@ def _iteration(st, P, backend: str, interpret: bool):
     st["iso_ctr"] = st["iso_ctr"] + jnp.any(newly2, axis=1)
     st["iso_reason"] = jnp.where(newly2, 2, st["iso_reason"])
     # gang hit: lost work (if RUNNING), software roll, session teardown
-    hit = jnp.take_along_axis(st["in_gang"],
-                              jnp.clip(fnode, 0, n - 1)[:, None],
-                              axis=1)[:, 0]
+    hit = jnp.any(st["in_gang"] & node_m, axis=1)
     ghit = m_kill & st["cur_on"] & hit
     flags = flags | jnp.where(ghit & st["cur_run"], F_LOST, 0)
-    u_sw = _row(P["u"], st["u_ptr"])
-    soft = ghit & (u_sw < P["p_soft"])
+    soft = ghit & rd.below(st, "p_soft")
     st["u_ptr"] = st["u_ptr"] + ghit
-    xf = _row(P["x_full"], st["x_ptr"])
+    xf = rd.x_full
     st["struct_until"] = jnp.where(
         soft, jnp.maximum(st["struct_until"], f64_add(t, xf)),
         st["struct_until"])
     st["x_ptr"] = st["x_ptr"] + soft
     st, flags = _fail_session(st, flags, P, ghit, fhw)
-    st, flags = _sched_next(st, flags, P, ghit, t, fdel, fhx, False)
+    st, flags = _sched_next(st, flags, P, rd, ghit, t, fdel, fhx, False)
     st["fail_ptr"] = st["fail_ptr"] + fdue
+    for k, v in rd.next_fail.items():
+        st[k] = jnp.where(fdue, v, st[k])
 
     # 5b. escalation crash, only once the failure queue at t has drained
     # (the numpy loop processes failures then escalations per iteration)
-    nf2 = _row(P["ft"], st["fail_ptr"])
-    ne = _row(P["et"], st["esc_ptr"])
+    nf2 = st["f_t"]
+    ne = st["e_t"]
     edue = alive & (ne <= t_eps) & ~(nf2 <= t_eps)
-    en = _row(P["enode"], st["esc_ptr"])
-    ehit_node = jnp.take_along_axis(st["in_gang"],
-                                    jnp.clip(en, 0, n - 1)[:, None],
-                                    axis=1)[:, 0]
+    en = st["e_node"]
+    ehit_node = jnp.any(st["in_gang"] & (iota_n == en[:, None]), axis=1)
     ehit = edue & st["cur_on"] & ehit_node
     flags = flags | jnp.where(ehit & st["cur_run"], F_LOST, 0)
-    u_sw2 = _row(P["u"], st["u_ptr"])
-    soft2 = ehit & (u_sw2 < P["p_soft"])
+    soft2 = ehit & rd.below(st, "p_soft")
     st["u_ptr"] = st["u_ptr"] + ehit
-    xf2 = _row(P["x_full"], st["x_ptr"])
+    xf2 = rd.x_full
     st["struct_until"] = jnp.where(
         soft2, jnp.maximum(st["struct_until"], f64_add(t, xf2)),
         st["struct_until"])
     st["x_ptr"] = st["x_ptr"] + soft2
     st, flags = _fail_session(st, flags, P, ehit, zero_b)
-    st, flags = _sched_next(st, flags, P, ehit, t, nan_v, zero_b, False)
+    st, flags = _sched_next(st, flags, P, rd, ehit, t, nan_v, zero_b,
+                            False)
     st["esc_ptr"] = st["esc_ptr"] + edue
-    ne2 = _row(P["et"], st["esc_ptr"])
+    esc = {k: st[k] for k in ("e_t", "e_node")}
+    st.update(lax.cond(jnp.any(edue), lambda: _esc_row(T, st["esc_ptr"]),
+                       lambda: esc))
+    ne2 = st["e_t"]
 
     # 6. next-event horizon (same-time candidates mask to +inf; the
     # duration term keeps the min finite, exactly the numpy fallback)
@@ -417,11 +544,12 @@ def _iteration(st, P, backend: str, interpret: bool):
     st["alive"] = alive & ~finishing
     st["t"] = jnp.where(st["alive"], t_next, st["t"])
 
-    # cap sentries: a lane within one iteration's worth of consumption of
-    # any cap is flagged and halted before a clipped read can corrupt it
-    U, M, X = P["u"].shape[1], P["man_day"].shape[1], P["x_half"].shape[1]
-    lane_over = (st["u_ptr"] > U - 8) | (st["m_ptr"] > M - 4) \
-        | (st["x_ptr"] > X - 4)
+    # cap sentries: a lane within its margin of the end of any capped
+    # tape is flagged and halted before a read can leave the tape
+    lane_over = zero_b
+    for ptr, tape in CAPPED.items():
+        lane_over = lane_over \
+            | (st[ptr] > P[tape].shape[1] - MARGIN[ptr])
     if "se_gang" in st:
         lane_over = lane_over \
             | (st["n_sessions"] > st["se_gang"].shape[1] - 2)
@@ -446,6 +574,9 @@ def wavefront_core(P, *, n_nodes: int, n_sessions: int, n_iters: int,
     with no degradation window has no reader for them."""
     L = P["u"].shape[0]
     n, NS, I = n_nodes, n_sessions, n_iters
+    if n > 0xFFFF:
+        raise ValueError(f"{n} nodes: a failure row packs its node in 16 "
+                         "bits")
     f64 = P["u"].dtype                 # int64 bit patterns
     st = {
         "t": jnp.zeros(L, f64),
@@ -485,8 +616,12 @@ def wavefront_core(P, *, n_nodes: int, n_sessions: int, n_iters: int,
     def cond(st):
         return jnp.any(st["alive"]) & (st["it"] < I)
 
+    T = _tape_tables(P)
+    st.update(f_t=T["ftd"][0], f_delay=T["ftd"][1], f_small=T["fsmall"][0],
+              e_t=T["et"][0], e_node=T["enode"][0])
+
     def body(st):
-        return _iteration(st, P, backend, interpret)
+        return _iteration(st, P, T, backend, interpret)
 
     st = lax.while_loop(cond, body, st)
     # lanes still alive at the iteration cap are cap overflows too
