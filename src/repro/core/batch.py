@@ -49,6 +49,7 @@ from repro.core.cluster import (RNG_STREAM_MANUAL, RNG_STREAM_STRUCT,
                                 TICK_H, _MAX_SPAN_TICKS, CampaignConfig,
                                 CampaignResult, ClusterSim)
 from repro.core.exclusion import ExclusionInterval, ExclusionTracker
+from repro.core.findings import LaneTotals, findings, split_downtimes
 from repro.core.failures import (CORRELATED_KINDS, DEGRADE_KINDS,
                                  KIND_NAMES, FailureBatch, FailureInjector,
                                  blind_windows, degradation_windows,
@@ -1171,71 +1172,36 @@ class BatchedCampaignEngine:
             degraded_hours=B.degraded[i])
 
     def _findings(self, B: _Batch, i: int) -> dict:
-        """`repro.ops.sweep.compute_findings` without the object graph —
-        identical formulas over the run-time accumulators (the F4 fold of
-        `chain_stats`, the tracker's count/top-3 arithmetic, the session
-        running-hour sum), so the values match the scalar path bit for
-        bit."""
+        """`repro.ops.sweep.compute_findings` without the object graph:
+        the run-time accumulators (the F4 fold of `chain_stats`, the
+        tracker's counts, the session running-hour fold) fill the record
+        `repro.core.findings` folds, so the values match the scalar path
+        bit for bit."""
         cfg = self.cfg
-        duration = cfg.duration_h
-        n_chains, n_attempts, succ = B.f4[i]
-        gaps = B.gaps[i]
-        counts = np.bincount(B.npart_all[i],
-                             minlength=cfg.n_nodes).astype(float) \
-            if B.npart_all[i] else np.zeros(cfg.n_nodes)
-        total = counts.sum()
-        top3 = float(np.sort(counts)[::-1][:3].sum() / total) \
-            if total else 0.0
-        delib_frac = float(B.n_delib[i] / max(B.n_intervals[i], 1))
-        autos = [d["hours"] for d in B.downtimes[i]
-                 if d["auto"] and d.get("kind") != "drain"]
-        mans = [d["hours"] for d in B.downtimes[i]
-                if not d["auto"] and d.get("kind") != "drain"]
-        run = B.run_sum[i] if cfg.job_nodes > 1 else 0.0
-        lost = B.lost[i]
-        ckpt_h = int(B.ckpt_events[i]) * cfg.checkpoint_save_s / 3600.0
-        plane = B.planes[i]
-        urgent_h = plane.stats.urgent_save_h if plane is not None else 0.0
-        # degraded hours are subtracted LAST, matching
-        # `CampaignResult.goodput_h`'s float fold order exactly
-        deg_h = float(np.sum(B.degraded[i]))
-        goodput_h = run - float(np.sum(lost)) - ckpt_h - urgent_h - deg_h
         o0, o1 = int(B.fails.offsets[i]), int(B.fails.offsets[i + 1])
-        kslice = B.fails.kind[o0:o1]
-        infra_n = int((kslice >= 3).sum())
-        # correlated band: event count and switch concentration (share of
-        # switch_degrade events landing on the busiest switch — the F3
-        # analogue at rack granularity)
-        corr_n = int((kslice >= 6).sum())
-        sw_ids = B.fails.switch[o0:o1][kslice == 6]
-        corr_top = float(np.bincount(sw_ids).max() / len(sw_ids)) \
-            if len(sw_ids) else 0.0
-        out = {
-            "occupancy": min(run / duration, 1.0),
-            "goodput": max(goodput_h, 0.0) / duration,
-            "n_failures": float(B.fails.count(i)),
-            "n_sessions": float(B.n_sessions[i]),
-            "ckpt_events": float(B.ckpt_events[i]),
-            "mean_lost_h": float(np.mean(lost)) if lost else 0.0,
-            "f3_top3_share": top3,
-            "f3_deliberate_fraction": delib_frac,
-            "f4_n_chains": float(n_chains),
-            "f4_n_attempts": float(n_attempts),
-            "f4_success_rate": succ / n_chains if n_chains else 0.0,
-            "f4_gap_median_min": float(np.median(gaps)) if gaps else None,
-            "f4_auto_downtime_h": float(np.median(autos)) if autos else None,
-            "f4_manual_downtime_h": float(np.median(mans)) if mans else None,
-            "infra_n_events": float(infra_n),
-            "infra_degraded_h": deg_h,
-            "corr_n_events": float(corr_n),
-            "corr_top_switch_share": corr_top,
-        }
-        if plane is not None:
-            ctl = plane.stats.summarize(B.fails.events(i), duration)
-            out.update({f"ctrl_{k}": v for k, v in ctl.items()})
-            drains = B.reason_counts[i].get("predictive drain")
-            out["ctrl_drain_excl_events"] = float(drains) if drains else 0.0
-        return out
+        kinds = B.fails.kind[o0:o1]
+        plane = B.planes[i]
+        autos, mans = split_downtimes(B.downtimes[i])
+        return findings(LaneTotals(
+            duration_h=cfg.duration_h,
+            run_h=B.run_sum[i] if cfg.job_nodes > 1 else 0.0,
+            lost_h=B.lost[i], ckpt_events=int(B.ckpt_events[i]),
+            save_s=cfg.checkpoint_save_s,
+            urgent_h=plane.stats.urgent_save_h if plane is not None
+            else 0.0,
+            degraded_h=B.degraded[i],
+            n_failures=B.fails.count(i), n_sessions=B.n_sessions[i],
+            excl_counts=np.bincount(B.npart_all[i], minlength=cfg.n_nodes)
+            if B.npart_all[i] else np.zeros(cfg.n_nodes),
+            n_deliberate=B.n_delib[i], n_intervals=B.n_intervals[i],
+            f4=tuple(B.f4[i]), gaps_min=B.gaps[i],
+            auto_down_h=autos, manual_down_h=mans,
+            infra_n=int((kinds >= 3).sum()),
+            corr_n=int((kinds >= 6).sum()),
+            switch_ids=B.fails.switch[o0:o1][kinds == 6],
+            control=plane.stats.summarize(B.fails.events(i), cfg.duration_h)
+            if plane is not None else None,
+            drain_excl=B.reason_counts[i].get("predictive drain", 0)))
 
 # ---------------------------------------------------------------------------
 # heterogeneous stacked dispatch (the what-if service's engine entry)
@@ -1293,9 +1259,9 @@ def run_findings_stacked(configs: Sequence[CampaignConfig],
                 covered[i] = per_cfg[j]
     out: List[Dict[int, List[dict]]] = []
     for i, cfg in enumerate(configs):
-        findings = covered.get(i)
-        if findings is None:
-            findings = BatchedCampaignEngine(
+        per_seed = covered.get(i)
+        if per_seed is None:
+            per_seed = BatchedCampaignEngine(
                 cfg, wavefront_backend="numpy").run_findings(seeds)
-        out.append(dict(zip(seeds, findings)))
+        out.append(dict(zip(seeds, per_seed)))
     return out

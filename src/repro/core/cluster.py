@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.checkpoint.youngdaly import MTBF_H_PAPER
 from repro.control.policy import ControlConfig, ControlPlane, ControlStats
+from repro.core import findings
 from repro.core.exclusion import ExclusionTracker
 from repro.storage.fabric import FabricConfig, StorageFabric
 from repro.core.failures import (CORRELATED_KINDS, DEGRADE_KINDS,
@@ -149,10 +150,15 @@ class CampaignResult:
                                              # per session: effective hours
                                              #   lost to degrade-band windows
 
+    def running_h(self) -> float:
+        """Running hours of the multi-node sessions, added left to right
+        in session order (`repro.core.findings`' float-order rule)."""
+        return findings.fold_left(
+            s.elapsed_running_h(self.duration_h) for s in self.sessions
+            if s.n_nodes > 1)
+
     def training_occupancy(self) -> float:
-        run = sum(s.elapsed_running_h(self.duration_h) for s in self.sessions
-                  if s.n_nodes > 1)
-        return min(run / self.duration_h, 1.0)
+        return findings.occupancy(self.running_h(), self.duration_h)
 
     def goodput_h(self) -> float:
         """Productive training hours: RUNNING wall time minus redone (lost)
@@ -162,16 +168,15 @@ class CampaignResult:
         control plane trades on: urgent saves spend save time to shrink
         the lost-work window; drains spend a controlled restart to dodge
         a crash."""
-        run = sum(s.elapsed_running_h(self.duration_h) for s in self.sessions
-                  if s.n_nodes > 1)
-        ckpt_h = self.checkpoint_events * self.checkpoint_save_s / 3600.0
-        urgent_h = self.control.urgent_save_h if self.control else 0.0
-        return run - float(np.sum(self.lost_hours)) - ckpt_h - urgent_h \
-            - float(np.sum(self.degraded_hours))
+        return findings.goodput_h(
+            self.running_h(), float(np.sum(self.lost_hours)),
+            self.checkpoint_events, self.checkpoint_save_s,
+            self.control.urgent_save_h if self.control else 0.0,
+            float(np.sum(self.degraded_hours)))
 
     def goodput(self) -> float:
         """Goodput as a fraction of the campaign wall clock."""
-        return max(self.goodput_h(), 0.0) / self.duration_h
+        return findings.goodput(self.goodput_h(), self.duration_h)
 
     def retry_chains(self) -> List[Chain]:
         """Chains with at least one retry (the paper's unit of analysis)."""

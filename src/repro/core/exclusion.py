@@ -17,6 +17,8 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.core.findings import top_k_share
+
 
 @dataclass
 class ExclusionInterval:
@@ -67,11 +69,7 @@ class ExclusionTracker:
 
     def top_k_share(self, k: int = 3) -> float:
         """Fraction of all exclusion events on the k most-excluded nodes."""
-        c = self.exclusion_counts().astype(float)
-        total = c.sum()
-        if total == 0:
-            return 0.0
-        return float(np.sort(c)[::-1][:k].sum() / total)
+        return top_k_share(self.exclusion_counts(), k)
 
     def by_reason(self) -> Dict[str, dict]:
         """Exclusion events grouped by reason — separates the injected

@@ -1,21 +1,13 @@
-"""Pallas kernels for the wavefront's contended inner passes.
+"""Pallas kernel for the wavefront's contended inner pass.
 
-Two kernels, both integer/compare-exact so they are drop-in on any
-backend (TPU Mosaic, or ``interpret=True`` on CPU for parity tests):
+Integer/compare-exact, so it is drop-in on any backend (TPU Mosaic, or
+``interpret=True`` on CPU for parity tests):
 
 * **gang selection** — the allocation row scan ``free & (rowcumsum(free)
   <= job)`` that picks the first ``job`` free nodes of every lane.  The
   cumsum is computed as a matmul against an upper-triangular ones matrix
   (MXU-friendly; counts are small integers, exact in f32), then compared
   against the per-lane gang size.
-* **storage-fabric slot-table query** — the analytic
-  ``expected_duration_s`` of the shared-NFS slot-table model evaluated
-  over a stacked batch of (op params, fanin, bytes) rows, for dense
-  sweep surfaces that probe the fabric at every grid point.  The float
-  formula has genuine mul-add chains, so *this* kernel is allclose-level
-  (1-ulp class), not bitwise: the numpy ``StorageFabric`` stays the
-  resolution oracle wherever parity matters (campaign setup), and the
-  compiled paths serve the wide analytic surfaces.
 """
 from __future__ import annotations
 
@@ -26,8 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-__all__ = ["gang_select_pallas", "fabric_query_ref", "fabric_query_pallas",
-           "GANG_ROWS", "N_LANES"]
+__all__ = ["gang_select_pallas", "GANG_ROWS", "N_LANES"]
 
 GANG_ROWS = 8        # lanes per gang-select block
 N_LANES = 128        # node-axis pad (TPU lane width)
@@ -78,57 +69,3 @@ def gang_select_pallas(free, job, *, interpret: bool = False):
     j = job.astype(jnp.float32)[:, None]
     out = _gang_blocks(f, j, interpret=interpret)
     return out[:, :n] > 0.5
-
-
-# -- storage-fabric slot-table query -----------------------------------------
-
-def fabric_query_ref(t_base, size, inflight, server_bw, t_queue, ctx,
-                     slots, link_bw, degradation, n_waves, jmean):
-    """Vector form of ``StorageFabric.expected_duration_s`` over stacked
-    query rows (all args broadcastable arrays; ``n_waves`` is the
-    pre-divided ``max(n_rpcs / slots, 1)`` and ``jmean`` the lognormal
-    mean factor, both host-computed)."""
-    t = t_base + size * inflight / server_bw \
-        + t_queue * jnp.maximum(inflight - ctx, 0.0) / ctx
-    t_svc = jnp.maximum(t * degradation, slots * size / link_bw)
-    return n_waves * t_svc * jmean
-
-
-_fabric_ref_jit = jax.jit(fabric_query_ref)
-
-
-def _fabric_kernel(tb, size, infl, sbw, tq, ctx, slots, lbw, deg, nw,
-                   jm, out_ref):
-    t = tb[...] + size[...] * infl[...] / sbw[...] \
-        + tq[...] * jnp.maximum(infl[...] - ctx[...], 0.0) / ctx[...]
-    t_svc = jnp.maximum(t * deg[...], slots[...] * size[...] / lbw[...])
-    out_ref[...] = nw[...] * t_svc * jm[...]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _fabric_blocks(args2d, *, interpret):
-    R, C = args2d[0].shape
-    spec = pl.BlockSpec((GANG_ROWS, C), _row_block)
-    return pl.pallas_call(
-        _fabric_kernel,
-        grid=(R // GANG_ROWS,),
-        in_specs=[spec] * len(args2d),
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((R, C), args2d[0].dtype),
-        interpret=interpret,
-    )(*args2d)
-
-
-def fabric_query_pallas(*args, interpret: bool = False):
-    """Pallas evaluation of :func:`fabric_query_ref` over (Q,) rows."""
-    q = args[0].shape[0]
-    rows = -(-q // N_LANES)
-    rpad = -(-rows // GANG_ROWS) * GANG_ROWS
-    total = rpad * N_LANES
-    padded = []
-    for a in args:
-        f = jnp.zeros(total, dtype=jnp.float32)
-        f = f.at[:q].set(a.astype(jnp.float32))
-        padded.append(f.reshape(rpad, N_LANES))
-    out = _fabric_blocks(tuple(padded), interpret=interpret)
-    return out.reshape(-1)[:q]

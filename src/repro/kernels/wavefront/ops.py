@@ -15,8 +15,9 @@ identical** to ``BatchedCampaignEngine.run_findings`` / the scalar
 3. **host replay** (here) — the float accounting folds (checkpoint
    catch-up, lost work, run-hours, downtime windows, retry-gap lists,
    degradation overlaps) rerun in numpy along the iteration axis, where
-   C-double arithmetic matches the scalar engine bit for bit; findings
-   assemble with the exact ``_findings`` formulas.
+   C-double arithmetic matches the scalar engine bit for bit; each
+   lane's totals go through the one findings fold
+   (``repro.core.findings``).
 
 Dispatch rules: the compiled core covers the control-free scope —
 ``cfg.telemetry`` off and ``cfg.control is None`` (reactive presets, all
@@ -48,14 +49,15 @@ import numpy as np
 
 from repro import tracing
 from repro.core.cluster import CampaignConfig, ClusterSim
+from repro.core.findings import LaneTotals, findings
 from repro.core.failures import (FailureInjector, degraded_overlap_h,
                                  has_correlated_band)
-from repro.kernels.common import (WAVEFRONT_MIN_SEEDS, next_pow2, on_tpu,
+from repro.kernels.common import (WAVEFRONT_MIN_SEEDS, on_tpu,
                                   validate_backend)
 from repro.kernels.wavefront.ref import (F_ADVANCE, F_ALLOCFAIL,
                                          F_CHAIN_CLOSE, F_FINALIZE,
                                          F_LOST, F_PREP_OK, F_RUNNING,
-                                         F_SESS_FAIL, F_START, F_VALID,
+                                         F_SESS_FAIL, F_START,
                                          unpack_gang, wavefront_core)
 from repro.kernels.wavefront.tapes import (LaneTables, WavefrontCaps,
                                            build_lane_tables,
@@ -63,8 +65,7 @@ from repro.kernels.wavefront.tapes import (LaneTables, WavefrontCaps,
                                            max_failures, pad_lanes_pow2)
 
 __all__ = ["compiled_eligible", "resolve_wavefront_backend",
-           "run_findings_compiled", "run_findings_grid",
-           "fabric_query_batch"]
+           "run_findings_compiled", "run_findings_grid"]
 
 _MAX_CAP_RETRIES = 6
 
@@ -361,53 +362,27 @@ def _degraded(tables: LaneTables, host, R: _Replay,
     return out
 
 
-def _median(values: np.ndarray) -> Optional[float]:
-    return float(np.median(values)) if len(values) else None
-
-
 def _lane_findings(tables: LaneTables, host, R: _Replay,
                    lane: int) -> dict:
-    duration = float(tables.duration[lane])
-    n_chains, n_attempts, succ = (int(v) for v in R.f4[lane])
-    gaps = R.gaps.lane(lane)
-    counts = host["npart_counts"][lane].astype(float)
-    total = counts.sum()
-    top3 = float(np.sort(counts)[::-1][:3].sum() / total) \
-        if total else 0.0
-    delib_frac = float(int(host["n_delib"][lane])
-                       / max(int(host["n_intervals"][lane]), 1))
     down_h = R.downtimes.lane(lane, 0)
     auto = R.downtimes.lane(lane, 1).astype(bool)
-    autos, mans = down_h[auto], down_h[~auto]
-    run = float(R.run_sum[lane]) if tables.job_gt1[lane] else 0.0
-    lost = R.lost.lane(lane)
-    ckpt_h = int(R.ckpt_events[lane]) \
-        * float(tables.save_s[lane]) / 3600.0
-    degraded = _degraded(tables, host, R, lane)
-    deg_h = float(np.sum(degraded))
-    goodput_h = run - float(np.sum(lost)) - ckpt_h - 0.0 - deg_h
-    return {
-        "occupancy": min(run / duration, 1.0),
-        "goodput": max(goodput_h, 0.0) / duration,
-        "n_failures": float(tables.n_failures[lane]),
-        "n_sessions": float(host["n_sessions"][lane]),
-        "ckpt_events": float(R.ckpt_events[lane]),
-        "mean_lost_h": float(np.mean(lost)) if len(lost) else 0.0,
-        "f3_top3_share": top3,
-        "f3_deliberate_fraction": delib_frac,
-        "f4_n_chains": float(n_chains),
-        "f4_n_attempts": float(n_attempts),
-        "f4_success_rate": succ / n_chains if n_chains else 0.0,
-        "f4_gap_median_min": _median(gaps),
-        "f4_auto_downtime_h": _median(autos),
-        "f4_manual_downtime_h": _median(mans),
-        "infra_n_events": float(tables.infra_n[lane]),
-        "infra_degraded_h": deg_h,
+    return findings(LaneTotals(
+        duration_h=float(tables.duration[lane]),
+        run_h=float(R.run_sum[lane]) if tables.job_gt1[lane] else 0.0,
+        lost_h=R.lost.lane(lane), ckpt_events=int(R.ckpt_events[lane]),
+        save_s=float(tables.save_s[lane]), urgent_h=0.0,
+        degraded_h=_degraded(tables, host, R, lane),
+        n_failures=int(tables.n_failures[lane]),
+        n_sessions=int(host["n_sessions"][lane]),
+        excl_counts=host["npart_counts"][lane],
+        n_deliberate=int(host["n_delib"][lane]),
+        n_intervals=int(host["n_intervals"][lane]),
+        f4=tuple(R.f4[lane].tolist()), gaps_min=R.gaps.lane(lane),
+        auto_down_h=down_h[auto], manual_down_h=down_h[~auto],
+        infra_n=int(tables.infra_n[lane]),
         # eligibility excludes the correlated band, so these lanes carry
         # no switch_degrade / dns_flap events by construction
-        "corr_n_events": 0.0,
-        "corr_top_switch_share": 0.0,
-    }
+        corr_n=0, switch_ids=(), control=None, drain_excl=0))
 
 
 # -- public entry points -----------------------------------------------------
@@ -479,55 +454,3 @@ def run_findings_compiled(config: CampaignConfig, seeds: Sequence[int],
     """Single-config form of :func:`run_findings_grid`."""
     return run_findings_grid([config], seeds, backend=backend,
                              interpret=interpret, caps=caps)[0]
-
-
-def fabric_query_batch(fabric, op, fanins, bytes_per_client, *,
-                       slots_per_client=None, rpc_bytes=None,
-                       backend: str = "numpy",
-                       interpret: Optional[bool] = None) -> np.ndarray:
-    """Batched ``StorageFabric.expected_duration_s`` over stacked query
-    rows (``fanins``/``bytes_per_client`` broadcast together).
-
-    ``backend='numpy'`` evaluates through the fabric itself (the bitwise
-    resolution oracle); ``'xla'`` evaluates the same analytic formula on
-    device in f64 (1-ulp class; the mul-add chains may contract to FMA)
-    and ``'pallas'`` in f32 lane tiles (~1e-7 relative) — both for wide
-    sweep surfaces, never for campaign setup."""
-    from repro.storage.fabric import _std_rpc_bytes, _std_slots
-    validate_backend(backend, what="fabric query backend")
-    fanins = np.atleast_1d(np.asarray(fanins))
-    byts = np.broadcast_to(np.atleast_1d(np.asarray(bytes_per_client)),
-                           fanins.shape)
-    slots = _std_slots(op) if slots_per_client is None else slots_per_client
-    size = _std_rpc_bytes(op) if rpc_bytes is None else rpc_bytes
-    if backend == "numpy":
-        return np.array([fabric.expected_duration_s(
-            op, int(f), int(b), slots_per_client=slots, rpc_bytes=size)
-            for f, b in zip(fanins, byts)])
-    cfg = fabric.config
-    server_bw, ctx, t_base, t_queue = cfg.op_params(op)
-    inflight = np.maximum(fanins.astype(np.int64), 1) * slots
-    n_rpcs = np.maximum(np.ceil(byts / size), 1.0)
-    n_waves = np.maximum(n_rpcs / slots, 1.0)
-    jmean = float(np.exp(cfg.service_jitter ** 2 / 2.0))
-    args = (np.full_like(n_waves, t_base), np.full_like(n_waves, size),
-            inflight.astype(float), np.full_like(n_waves, server_bw),
-            np.full_like(n_waves, t_queue), np.full_like(n_waves, ctx),
-            np.full_like(n_waves, slots),
-            np.full_like(n_waves, cfg.client_link_bw),
-            np.full_like(n_waves, cfg.degradation), n_waves,
-            np.full_like(n_waves, jmean))
-    import jax.numpy as jnp
-    if backend == "pallas":
-        from repro.kernels.wavefront.kernel import fabric_query_pallas
-        if interpret is None:
-            interpret = not on_tpu()
-        out = fabric_query_pallas(*(jnp.asarray(a) for a in args),
-                                  interpret=interpret)
-        return np.asarray(out, dtype=float)
-    import jax
-
-    from repro.kernels.wavefront.kernel import _fabric_ref_jit
-    with jax.enable_x64(True):
-        out = _fabric_ref_jit(*(jnp.asarray(a) for a in args))
-        return np.asarray(out, dtype=float)

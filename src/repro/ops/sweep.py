@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core.cluster import ClusterSim
 from repro.core.failures import CORRELATED_KINDS, INFRA_KINDS
+from repro.core.findings import LaneTotals, findings, split_downtimes
 from repro.core.retry import chain_stats
 from repro.ops.scenario import Scenario, get_scenario
 
@@ -62,60 +63,36 @@ PAPER_REFERENCE = {
 # per-campaign worker (module-level: must pickle for ProcessPoolExecutor)
 # ---------------------------------------------------------------------------
 
-def _top_switch_share(failures) -> float:
-    """Share of switch_degrade events landing on the busiest switch (same
-    bincount arithmetic as the batched engine's `_findings`)."""
-    sw = [f.switch for f in failures if f.kind == "switch_degrade"]
-    if not sw:
-        return 0.0
-    return float(np.bincount(np.asarray(sw)).max() / len(sw))
-
-
 def compute_findings(res) -> Dict[str, Optional[float]]:
     """F2-F4 metrics (plus campaign health) from one CampaignResult."""
-    st = chain_stats(res.retry_chains())
-    excl = res.exclusions.summary()
-    # drain episodes are controlled handoffs, not recovery downtime — keep
-    # the F4 medians comparable with the paper's reactive measurements
-    autos = [d["hours"] for d in res.downtimes
-             if d["auto"] and d.get("kind") != "drain"]
-    mans = [d["hours"] for d in res.downtimes
-            if not d["auto"] and d.get("kind") != "drain"]
-    out = {
-        "occupancy": res.training_occupancy(),
-        "goodput": res.goodput(),
-        "n_failures": float(len(res.failures)),
-        "n_sessions": float(len(res.sessions)),
-        "ckpt_events": float(res.checkpoint_events),
-        "mean_lost_h": float(np.mean(res.lost_hours))
-        if res.lost_hours else 0.0,
-        "f3_top3_share": excl["top3_share"],
-        "f3_deliberate_fraction": excl["deliberate_fraction"],
-        "f4_n_chains": float(st["n_chains"]),
-        "f4_n_attempts": float(st["n_attempts"]),
-        "f4_success_rate": st["chain_success_rate"],
-        "f4_gap_median_min": st["gap_median_min"],
-        "f4_auto_downtime_h": float(np.median(autos)) if autos else None,
-        "f4_manual_downtime_h": float(np.median(mans)) if mans else None,
-        # infra fault band: degrade-don't-kill events and the effective
-        # hours their windows ate (always present, 0.0 without the band)
-        "infra_n_events": float(sum(1 for f in res.failures
-                                    if f.kind in INFRA_KINDS)),
-        "infra_degraded_h": float(np.sum(res.degraded_hours)),
-        # correlated fault band: event count and switch concentration (the
-        # share of switch_degrade events on the busiest leaf switch — F3 at
-        # rack granularity; 0.0 without the band)
-        "corr_n_events": float(sum(1 for f in res.failures
-                                   if f.kind in CORRELATED_KINDS)),
-        "corr_top_switch_share": _top_switch_share(res.failures),
-    }
+    chains = res.retry_chains()
+    st = chain_stats(chains)
+    intervals = res.exclusions.intervals
+    autos, mans = split_downtimes(res.downtimes)
+    kinds = [f.kind for f in res.failures]
+    ctl, drain_excl = None, 0
     if res.control is not None:
         ctl = res.control.summarize(res.failures, res.duration_h)
-        out.update({f"ctrl_{k}": v for k, v in ctl.items()})
-        drain_excl = res.exclusions.by_reason().get("predictive drain")
-        out["ctrl_drain_excl_events"] = \
-            float(drain_excl["count"]) if drain_excl else 0.0
-    return out
+        drains = res.exclusions.by_reason().get("predictive drain")
+        drain_excl = drains["count"] if drains else 0
+    return findings(LaneTotals(
+        duration_h=res.duration_h, run_h=res.running_h(),
+        lost_h=res.lost_hours, ckpt_events=res.checkpoint_events,
+        save_s=res.checkpoint_save_s,
+        urgent_h=res.control.urgent_save_h if res.control else 0.0,
+        degraded_h=res.degraded_hours,
+        n_failures=len(res.failures), n_sessions=len(res.sessions),
+        excl_counts=res.exclusions.exclusion_counts(),
+        n_deliberate=sum(iv.deliberate for iv in intervals),
+        n_intervals=len(intervals),
+        f4=(st["n_chains"], st["n_attempts"], st["success"]),
+        gaps_min=[g for c in chains for g in c.gaps_min()],
+        auto_down_h=autos, manual_down_h=mans,
+        infra_n=sum(k in INFRA_KINDS for k in kinds),
+        corr_n=sum(k in CORRELATED_KINDS for k in kinds),
+        switch_ids=[f.switch for f in res.failures
+                    if f.kind == "switch_degrade"],
+        control=ctl, drain_excl=drain_excl))
 
 
 def _f1_findings(scenario: Scenario, seed: int) -> Dict[str, float]:

@@ -148,13 +148,6 @@ def test_gang_blocks_compiles(x64, one_chip, quiet_cache):
                            interpret=False).compile()
 
 
-def test_fabric_blocks_compiles(one_chip, quiet_cache):
-    from repro.kernels.wavefront.kernel import N_LANES, _fabric_blocks
-    args = tuple(_spec((64, N_LANES), jnp.float32, one_chip)
-                 for _ in range(11))
-    _fabric_blocks.lower(args, interpret=False).compile()
-
-
 def test_ckpt_pack_blocks_compiles(one_chip, quiet_cache):
     from repro.kernels.ckpt_pack.kernel import ckpt_pack_blocks
     fn = jax.jit(functools.partial(ckpt_pack_blocks, interpret=False))
