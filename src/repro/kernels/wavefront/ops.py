@@ -14,9 +14,9 @@ identical** to ``BatchedCampaignEngine.run_findings`` / the scalar
    windows, per-session gang bitmasks (packed);
 3. **host replay** (here) — the float accounting folds (checkpoint
    catch-up, lost work, run-hours, downtime windows, retry-gap lists,
-   degradation overlaps) rerun in numpy along the iteration axis, where
-   C-double arithmetic matches the scalar engine bit for bit; each
-   lane's totals go through the one findings fold
+   degradation overlaps) rerun in numpy, event by event in each lane's
+   order, where C-double arithmetic matches the scalar engine bit for
+   bit; each lane's totals go through the one findings fold
    (``repro.core.findings``).
 
 Dispatch rules: the compiled core covers the control-free scope —
@@ -37,8 +37,10 @@ ever read from a clean pass.
 
 Tracing counters (``repro.tracing``): ``grid.cap_reruns`` (device passes
 rerun), ``grid.gang_mask_bytes`` (session gang masks fetched from the
-device, every pass) and ``grid.iterations`` (the clean pass's iteration
-count).  ``last_grid_pass`` keeps the last grid call's numbers whether
+device, every pass), ``grid.iterations`` (the clean pass's iteration
+count), ``grid.replay_events`` (event cells the replay folds outside a
+loop) and ``grid.replay_ckpt_rows`` (steps of its checkpoint catch-up
+loop).  ``last_grid_pass`` keeps the last grid call's numbers whether
 or not tracing is on.
 """
 from __future__ import annotations
@@ -170,17 +172,35 @@ def _run_with_caps(build, caps: WavefrontCaps, backend: str,
 
 # -- host replay of the float accounting folds -------------------------------
 
+# event bits the replay folds outside its catch-up loop, and the record
+# bits of a checkpoint catch-up (the clock advanced, the session RUNNING)
+_ATT = F_START | F_ALLOCFAIL
+_EVENTS = (_ATT | F_PREP_OK | F_SESS_FAIL | F_LOST | F_CHAIN_CLOSE
+           | F_FINALIZE)
+_CATCH_UP = F_ADVANCE | F_RUNNING
+
+
 class _Lists:
-    """Per-lane lists of values (one or more columns) appended in replay
-    order: kept as (lanes, values) chunks, one per replay step, and
-    grouped by lane at the first read (a stable sort keeps each lane's
-    order)."""
+    """Per-lane lists of values (one or more columns) in append order:
+    kept as (lanes, values) chunks and grouped by lane at the first read
+    (a stable sort keeps each lane's order), or built already grouped
+    (``grouped``)."""
 
     def __init__(self, L: int, n_cols: int):
         self.L = L
         self._lanes: List[np.ndarray] = []
         self._chunks: List[List[np.ndarray]] = [[] for _ in range(n_cols)]
         self._cols: Optional[List[np.ndarray]] = None
+
+    @classmethod
+    def grouped(cls, bounds: np.ndarray, pos: np.ndarray,
+                *cols: np.ndarray) -> "_Lists":
+        """Lists whose values ``cols`` sit at the sorted lane-major
+        positions ``pos``, lane ``s`` owning ``bounds[s]:bounds[s + 1]``."""
+        out = cls(len(bounds) - 1, len(cols))
+        out._cols = list(cols)
+        out._bounds = np.searchsorted(pos, bounds)
+        return out
 
     def add(self, lanes: np.ndarray, *cols: np.ndarray) -> None:
         self._lanes.append(lanes)
@@ -200,20 +220,11 @@ class _Lists:
 
 
 class _Replay:
-    """Per-lane accounting state driven by the device record stream."""
+    """What the findings fold reads of the replay, per lane: checkpoint
+    events, running hours, F4's retry-chain counts and four lists."""
 
     def __init__(self, L: int):
-        self.cur_t = np.zeros(L)
-        self.last_ckpt = np.zeros(L)
-        self.last_save = np.zeros(L)
         self.ckpt_events = np.zeros(L, dtype=np.int64)
-        self.started = np.full(L, np.nan)
-        self.open_sess = np.zeros(L, dtype=bool)
-        self.prev_end = np.full(L, np.nan)
-        self.down_since = np.full(L, np.nan)
-        self.down_auto = np.ones(L, dtype=bool)
-        self.n_att = np.zeros(L, dtype=np.int64)
-        self.retry_reached = np.zeros(L, dtype=bool)
         self.run_sum = np.zeros(L)
         self.f4 = np.zeros((L, 3), dtype=np.int64)
         self.gaps = _Lists(L, 1)         # retry gap, minutes
@@ -222,122 +233,254 @@ class _Replay:
         self.sess = _Lists(L, 2)         # session start, end
 
 
+def _lane_major(x: np.ndarray, tile: int = 128) -> np.ndarray:
+    """``x`` (rows, lanes) as (lanes, rows) in C order, copied tile by
+    tile: a whole-array transpose reads one side strided, out of cache."""
+    n, L = x.shape
+    out = np.empty((L, n), dtype=x.dtype)
+    for r in range(0, n, tile):
+        for s in range(0, L, tile):
+            out[s:s + tile, r:r + tile] = x[r:r + tile, s:s + tile].T
+    return out
+
+
+def _last(pos: np.ndarray, q: np.ndarray) -> np.ndarray:
+    """Per query, the latest of the sorted event positions ``pos`` at or
+    before it; -1 where none."""
+    i = np.searchsorted(pos, q, "right") - 1
+    return np.where(i >= 0, pos[i], -1) if len(pos) else np.full_like(q, -1)
+
+
+def _next(pos: np.ndarray, q: np.ndarray, end: int) -> np.ndarray:
+    """Per query, the first of the sorted event positions ``pos`` at or
+    after it; ``end`` where none."""
+    i = np.searchsorted(pos, q)
+    if not len(pos):
+        return np.full_like(q, end)
+    return np.where(i < len(pos), pos[np.minimum(i, len(pos) - 1)], end)
+
+
+def _shift(vals: np.ndarray, pos: np.ndarray, bounds: np.ndarray,
+           init) -> np.ndarray:
+    """Per element at the sorted lane-major positions ``pos``, the value
+    of the element before it where that one is in the same lane (lane
+    ``s`` owns positions ``bounds[s]:bounds[s + 1]``), else ``init``."""
+    out = np.empty_like(vals)
+    out[1:] = vals[:-1]
+    lead = np.searchsorted(pos, bounds[:-1])
+    out[lead[lead < len(pos)]] = init
+    return out
+
+
+def _counts(vals: np.ndarray) -> np.ndarray:
+    """``c[i]``: the sum of the integer ``vals[:i]``."""
+    c = np.zeros(len(vals) + 1, dtype=np.int64)
+    np.cumsum(vals, out=c[1:])
+    return c
+
+
+def _per_lane(vals: np.ndarray, bounds: np.ndarray) -> np.ndarray:
+    """Integer ``vals`` of lane-major elements summed per lane (lane
+    ``s`` owns ``bounds[s]:bounds[s + 1]``)."""
+    c = _counts(vals)
+    return c[bounds[1:]] - c[bounds[:-1]]
+
+
+def _catch_up(interval: np.ndarray, flags: np.ndarray, clock: np.ndarray,
+              prep_at: np.ndarray, lost_at: np.ndarray):
+    """The checkpoint catch-up, the one fold that steps (``ref.py``'s
+    docstring): per lane, ``last_ckpt`` and ``last_save`` reset to the
+    clock at each PREP_OK, ``last_save`` read at each LOST, and at each
+    row where the lane is RUNNING at an advanced clock the scalar
+    engine's catch-up.  A lane's steps depend on each other and on no
+    other lane's (a lane that is not RUNNING adds 0 checkpoints), so
+    step ``j`` of the loop takes every lane's ``j``-th such row.
+
+    ``flags`` and ``clock`` are ``rec_flags`` and ``rec_t`` lane-major,
+    (lanes, rows); ``prep_at`` and ``lost_at`` the lane-major positions
+    of the PREP_OK and LOST events.  Returns the checkpoint events per
+    lane and ``last_save`` as each LOST event sees it."""
+    L, n = flags.shape
+    fv, cv = flags.ravel(), clock.ravel()
+    due = (fv & _CATCH_UP) == _CATCH_UP
+    due[prep_at] = True
+    due[lost_at] = True
+    cells = np.flatnonzero(due)
+    bounds = np.searchsorted(cells, np.arange(L + 1) * n)
+    lane = np.repeat(np.arange(L), np.diff(bounds))
+    step = np.arange(len(cells)) - bounds[lane]
+    n_steps = int(step.max(initial=-1)) + 1
+    tracing.count("grid.replay_ckpt_rows", n_steps)
+
+    def by_step(vals):
+        out = np.zeros((n_steps, L), dtype=vals.dtype)
+        out[step, lane] = vals
+        return out
+
+    f = fv[cells]
+    run = by_step((f & _CATCH_UP) == _CATCH_UP)
+    prep_ok = by_step((f & F_PREP_OK) != 0)
+    lost = by_step((f & F_LOST) != 0)
+    entering = cv[cells - 1]            # the clock entering the row
+    entering[cells == lane * n] = 0.0
+    t_prep = by_step(entering)
+    t_run = by_step(cv[cells])
+    last_ckpt = np.zeros(L)
+    last_save = np.zeros(L)
+    ckpt_events = np.zeros(L, dtype=np.int64)
+    saves = np.empty((n_steps, L))
+    d = np.empty(L)
+    k = np.empty(L, dtype=np.int64)
+    for j, p, s, u in zip(range(n_steps), prep_ok.any(axis=1).tolist(),
+                          lost.any(axis=1).tolist(),
+                          run.any(axis=1).tolist()):
+        if p:
+            np.putmask(last_ckpt, prep_ok[j], t_prep[j])
+            np.putmask(last_save, prep_ok[j], t_prep[j])
+        if s:
+            saves[j] = last_save
+        if u:
+            # k = floor((tn - last_ckpt + 1e-12) / interval), clamped at
+            # 0 and kept on RUNNING lanes; last_ckpt += k * interval
+            np.subtract(t_run[j], last_ckpt, out=d)
+            d += 1e-12
+            d /= interval
+            np.floor(d, out=d)
+            np.copyto(k, d, casting="unsafe")
+            np.maximum(k, 0, out=k)
+            k *= run[j]
+            ckpt_events += k
+            np.multiply(k, interval, out=d)
+            last_ckpt += d
+            np.maximum(last_save, last_ckpt, out=last_save)
+    i = np.searchsorted(cells, lost_at)
+    return ckpt_events, saves[step[i], lane[i]]
+
+
 def _replay(tables: LaneTables, host: Dict[str, np.ndarray]) -> _Replay:
-    """Rerun the float folds along the iteration axis.  Application
-    order within an iteration mirrors the numpy wavefront's step order
-    (starts -> prep-done -> session fail/lost -> chain close -> finalize
-    -> checkpoint catch-up), so every sequential float accumulation sees
-    the same operand sequence as the scalar engine."""
+    """Rerun the float folds of the device record stream, event by event.
+
+    Each lane's events apply in the numpy wavefront's order: by row, and
+    within a row by phase (attempt start or alloc-fail -> PREP_OK ->
+    session fail and its lost work -> chain close -> finalize ->
+    checkpoint catch-up).  Every state a phase reads is the value the
+    latest earlier event that sets it left, so it is found among the
+    lane's events by position (a shift, a search, or a difference of
+    cumulative counts), and the lists and running hours gather in event
+    order: every float accumulation sees the scalar engine's operands in
+    its order.  Only the checkpoint catch-up steps (``_catch_up``).
+
+    An event at row ``it`` sees the clock ``rec_t[it - 1]`` (0 at row
+    0): the device writes ``t_next`` for every lane every iteration, and
+    on a pending one that is the lane's clock itself.  Padding lanes
+    carry no events."""
     L = host["rec_t"].shape[1]
     R = _Replay(L)
-    interval = tables.interval
-    duration = tables.duration
-    it_count = int(host["it"])
-    rec_t, rec_fl = host["rec_t"], host["rec_flags"][:it_count]
-    isnan = np.isnan
+    n = int(host["it"])
+    # event cells in lane-major order, each lane's events in row order:
+    # an event's position in this order is what the folds compare; lane
+    # s owns positions bounds[s]:bounds[s + 1]
+    flags = _lane_major(host["rec_flags"][:n])
+    cell = np.flatnonzero((flags & _EVENTS) != 0)
+    N = len(cell)
+    tracing.count("grid.replay_events", N)
+    f = flags.ravel()[cell]
+    bounds = np.searchsorted(cell, np.arange(L + 1) * n)
+    clock = _lane_major(host["rec_t"][:n])
+    t = clock.ravel()[cell - 1]
+    lead = bounds[:-1][np.diff(bounds) > 0]
+    t[lead[cell[lead] % n == 0]] = 0.0
 
-    def flag(f):
-        """(iterations, L) mask of flag ``f``, and its rows' any."""
-        m = (rec_fl & f) != 0
-        return m, m.any(axis=1)
+    def at(bits):
+        return np.flatnonzero((f & bits) != 0)
 
-    M_START, _ = flag(F_START)
-    M_AF, _ = flag(F_ALLOCFAIL)
-    M_ATT = M_START | M_AF
-    ANY_ATT = M_ATT.any(axis=1)
-    M_POK, ANY_POK = flag(F_PREP_OK)
-    M_FAIL, ANY_FAIL = flag(F_SESS_FAIL)
-    M_LOST, ANY_LOST = flag(F_LOST)
-    M_CC, ANY_CC = flag(F_CHAIN_CLOSE)
-    M_FIN, ANY_FIN = flag(F_FINALIZE)
-    M_ADV, _ = flag(F_ADVANCE)
-    M_RUN = M_ADV & ((rec_fl & F_RUNNING) != 0)
-    ANY_RUN = M_RUN.any(axis=1)
-    ACTIVE = rec_fl.any(axis=1)
-    for it in range(it_count):
-        if not ACTIVE[it]:
-            continue
-        tn = rec_t[it]
-        t = R.cur_t
+    def lane_of(pos):
+        return np.searchsorted(bounds, pos, side="right") - 1
 
-        m_start, m_af, m_att = M_START[it], M_AF[it], M_ATT[it]
-        if ANY_ATT[it]:
-            gm = m_att & ~isnan(R.prev_end)
-            if gm.any():
-                idx = np.nonzero(gm)[0]
-                R.gaps.add(idx, (t[idx] - R.prev_end[idx]) * 60.0)
-            R.n_att[m_att] += 1
-            R.prev_end[m_af] = t[m_af]
-            R.prev_end[m_start] = np.nan
-            R.started[m_start] = np.nan
-            R.open_sess[m_start] = True
+    # retry gaps: an attempt reads the end of the lane's last attempt (an
+    # alloc-fail or a session fail); a START or a chain close clears it
+    s = at(_ATT | F_SESS_FAIL | F_CHAIN_CLOSE)
+    fs = f[s]
+    ends = ((fs & (F_SESS_FAIL | F_CHAIN_CLOSE)) == F_SESS_FAIL) \
+        | ((fs & (_ATT | F_SESS_FAIL | F_CHAIN_CLOSE)) == F_ALLOCFAIL)
+    prev_end = _shift(np.where(ends, t[s], np.nan), s, bounds, np.nan)
+    g = np.flatnonzero(((fs & _ATT) != 0) & ~np.isnan(prev_end))
+    gap_at = s[g]
+    R.gaps = _Lists.grouped(bounds, gap_at,
+                            (t[gap_at] - prev_end[g]) * 60.0)
 
-        m_pok = M_POK[it]
-        if ANY_POK[it]:
-            R.started[m_pok] = t[m_pok]
-            R.retry_reached[m_pok & (R.n_att != 1)] = True
-            R.last_ckpt[m_pok] = t[m_pok]
-            R.last_save[m_pok] = t[m_pok]
-            dc = m_pok & ~isnan(R.down_since)
-            idx = np.nonzero(dc)[0]
-            R.downtimes.add(idx, t[idx] - R.down_since[idx],
-                            R.down_auto[idx])
-            R.down_since[dc] = np.nan
-            R.down_auto[dc] = True
+    # attempts since the chain's last close or finalize (the chain's
+    # base), read at PREP_OK (retry reached after more than one), chain
+    # close and finalize (F4 counts each chain of more than one)
+    r = at(F_PREP_OK | F_CHAIN_CLOSE | F_FINALIZE)
+    fr, lr = f[r], lane_of(r)
+    rb = np.searchsorted(r, bounds)
+    is_pok = (fr & F_PREP_OK) != 0
+    is_cc = (fr & F_CHAIN_CLOSE) != 0
+    reset = (fr & (F_CHAIN_CLOSE | F_FINALIZE)) != 0
+    j = np.arange(len(r))
+    after_reset = np.maximum.accumulate(np.where(reset, j + 1, 0))
+    base = np.maximum(np.concatenate(([0], after_reset[:-1])), rb[lr])
+    start_of = np.where(base > rb[lr], r[base - 1] + 1, bounds[lr])
+    catt = _counts((f & _ATT) != 0)
+    n_att = catt[r + 1] - catt[start_of]
+    crr = _counts(is_pok & (n_att != 1))
+    reached = crr[j + 1] > crr[base]
+    closed = reset & (n_att > 1)
+    R.f4 = np.stack([_per_lane(closed, rb),
+                     _per_lane(np.where(closed, n_att, 0), rb),
+                     _per_lane(closed & reached, rb)], axis=1)
 
-        m_fail, m_lost = M_FAIL[it], M_LOST[it]
-        if ANY_FAIL[it]:
-            if ANY_LOST[it]:            # lost precedes the teardown fold
-                idx = np.nonzero(m_lost)[0]
-                R.lost.add(idx, np.minimum(t[idx] - R.last_save[idx],
-                                           interval[idx]))
-            rs = m_fail & ~isnan(R.started)
-            R.run_sum[rs] += np.maximum(0.0, t[rs] - R.started[rs])
-            idx = np.nonzero(m_fail)[0]
-            R.sess.add(idx, R.started[idx], t[idx])
-            R.started[m_fail] = np.nan
-            R.open_sess[m_fail] = False
-            R.prev_end[m_fail] = t[m_fail]
-            dn = m_fail & isnan(R.down_since)
-            R.down_since[dn] = t[dn]
+    # sessions: opened at START, closed by a session fail or, still open,
+    # at finalize; a session's start is the PREP_OK's clock if the lane's
+    # next close comes before its next START and PREP_OK
+    starts, fails = at(F_START), at(F_SESS_FAIL)
+    fin = r[(fr & F_FINALIZE) != 0]
+    last_start = _last(starts, fin)
+    open_ = (last_start >= bounds[lane_of(fin)]) \
+        & (last_start > _last(fails, fin)) \
+        & (last_start > _shift(fin, fin, bounds, -1))
+    fin = fin[open_]
+    closes = np.insert(fails, np.searchsorted(fails, fin), fin)
+    ends_at = t[closes]
+    ends_at[np.searchsorted(closes, fin)] = tables.duration[lane_of(fin)]
+    pok = r[is_pok]
+    c = np.searchsorted(closes, pok)
+    nc = np.append(closes, N)[c]
+    ran = (nc < bounds[lane_of(pok) + 1]) \
+        & (nc < _next(starts, pok + 1, N)) & (nc < np.append(pok[1:], N))
+    started = np.full(len(closes), np.nan)
+    started[c[ran]] = t[pok[ran]]
+    R.sess = _Lists.grouped(bounds, closes, started, ends_at)
+    if ran.any():
+        # running hours, summed left to right per lane from 0.0
+        k = c[ran]
+        terms = np.maximum(0.0, ends_at[k] - started[k])
+        tl = lane_of(closes[k])
+        rank = np.arange(len(k)) - np.searchsorted(tl, tl) + 1
+        M = np.zeros((L, rank.max() + 1))
+        M[tl, rank] = terms
+        R.run_sum = np.add.accumulate(M, axis=1)[:, -1]
 
-        m_cc = M_CC[it]
-        if ANY_CC[it]:
-            g = m_cc & (R.n_att > 1)
-            R.f4[g, 0] += 1
-            R.f4[g, 1] += R.n_att[g]
-            R.f4[g & R.retry_reached, 2] += 1
-            R.n_att[m_cc] = 0
-            R.retry_reached[m_cc] = False
-            R.prev_end[m_cc] = np.nan
-            R.down_auto[m_cc] = False
+    # downtime: from the first session fail after the lane's last PREP_OK
+    # to the next PREP_OK; automatic unless a chain closed in between
+    since = _next(fails, np.maximum(_shift(pok, pok, bounds, -1),
+                                    bounds[lane_of(pok)]), N)
+    dc = since < pok
+    dc_r = np.zeros(len(r), dtype=bool)
+    dc_r[np.flatnonzero(is_pok)[dc]] = True
+    a = np.flatnonzero(is_cc | dc_r)
+    auto = _shift(~is_cc[a], r[a], bounds, True)[dc_r[a]]
+    p = pok[dc]
+    R.downtimes = _Lists.grouped(bounds, p, t[p] - t[since[dc]], auto)
 
-        m_fin = M_FIN[it]
-        if ANY_FIN[it]:
-            fo = m_fin & R.open_sess
-            rs = fo & ~isnan(R.started)
-            R.run_sum[rs] += np.maximum(0.0, duration[rs] - R.started[rs])
-            idx = np.nonzero(fo)[0]
-            R.sess.add(idx, R.started[idx], duration[idx])
-            R.open_sess[fo] = False
-            R.started[fo] = np.nan
-            g = m_fin & (R.n_att > 1)
-            R.f4[g, 0] += 1
-            R.f4[g, 1] += R.n_att[g]
-            R.f4[g & R.retry_reached, 2] += 1
-            R.n_att[m_fin] = 0
-            R.retry_reached[m_fin] = False
-
-        m_run = M_RUN[it]
-        if ANY_RUN[it]:
-            k = np.floor((tn - R.last_ckpt + 1e-12)
-                         / interval).astype(np.int64)
-            k = np.where(m_run, np.maximum(k, 0), 0)
-            R.ckpt_events += k
-            R.last_ckpt += k * interval
-            np.maximum(R.last_save, R.last_ckpt, out=R.last_save)
-
-        R.cur_t = np.where(M_ADV[it], tn, R.cur_t)
+    # lost work (a LOST comes with its session fail): since the last
+    # save, as the catch-up leaves it
+    lost = at(F_LOST)
+    R.ckpt_events, saved = _catch_up(tables.interval, flags, clock,
+                                     cell[r[is_pok]], cell[lost])
+    R.lost = _Lists.grouped(bounds, lost, np.minimum(
+        t[lost] - saved, tables.interval[lane_of(lost)]))
     return R
 
 

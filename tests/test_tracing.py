@@ -170,6 +170,18 @@ def test_grid_cap_reruns_counted():
     assert snap["spans"]["grid.tapes"]["calls"] == reruns + 1
 
 
+def test_grid_replay_counters():
+    """The replay counts the event cells it folds without a loop and the
+    steps of its checkpoint catch-up loop, which takes each lane's
+    catch-up rows in turn, so never more steps than device iterations."""
+    tracing.enable()
+    run_findings_grid(_grid_configs(), list(range(8)), backend="xla")
+    counters = tracing.snapshot()["counters"]
+    assert counters["grid.replay_events"] > 0
+    assert 0 < counters["grid.replay_ckpt_rows"] \
+        <= counters["grid.iterations"]
+
+
 def test_proactive_findings_identical_and_spans_named():
     cfg = get_scenario("proactive").replace(
         duration_days=1.0, detector_backend="xla").to_campaign_config(0)
